@@ -38,6 +38,20 @@ from typing import Callable, Dict, Optional, Sequence
 REPO_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` points (JAX reads the variable itself);
+    when it is unset, at the fixed ``<repo>/.jax_cache``, so a second run
+    of the same program from this checkout loads what the first compiled.
+    Call before the first compile; returns the directory in use."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(os.path.abspath(REPO_ROOT),
+                                       ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
 def worker_env(devices: int = 1, base: Optional[dict] = None) -> dict:
     """Subprocess env with a forced host-device count: replaces any
     existing ``--xla_force_host_platform_device_count`` flag (device

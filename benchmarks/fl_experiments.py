@@ -9,6 +9,7 @@ min gamma / min bandwidth observed for FairEnergy (paper protocol).
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import time
@@ -33,7 +34,12 @@ def build(n_clients=20, rounds=60, n_train=12000, n_test=2000, seed=0,
           deadline=None, staleness_a=None, fault_rate=None, crash_rate=None,
           churn=None, defense=None, clusters=None, pool_frac=None,
           mobility_sigma=None, max_retx=None, burst_p=None,
-          price_outage=None, bits_grid=None):
+          price_outage=None, bits_grid=None, pallas=False):
+    """Trainer factory for the paper's setting. ``pallas`` routes the
+    solver and the top-k compression through the Pallas kernels
+    (``use_pallas_solver`` / ``use_pallas_compression``) instead of the
+    jnp paths. Returns ``(make, fl_cfg)``; ``make(controller, **kw)``
+    builds a ``FederatedTrainer``."""
     cfg = CNN_FULL
     scn = get_scenario(scenario) if isinstance(scenario, str) else scenario
     beta = scn.beta(0.3) if scn else 0.3
@@ -91,9 +97,10 @@ def build(n_clients=20, rounds=60, n_train=12000, n_test=2000, seed=0,
         # explicit CLI grid wins over the scenario preset: the solver's
         # decision grid becomes the joint (gamma, bits) cross product and
         # the engine quantizes payloads at the decided width
-        import dataclasses as _dc
-        fe_cfg = _dc.replace(fe_cfg,
-                             bits_grid=tuple(float(b) for b in bits_grid))
+        fe_cfg = dataclasses.replace(
+            fe_cfg, bits_grid=tuple(float(b) for b in bits_grid))
+    if pallas:
+        fe_cfg = dataclasses.replace(fe_cfg, use_pallas_solver=True)
     imgs, labels = make_fmnist_like(n_train, seed=seed, **DATA_KW)
     ti, tl = make_fmnist_like(n_test, seed=seed + 999,
                               **dict(DATA_KW, label_noise=0.0))
@@ -120,7 +127,8 @@ def build(n_clients=20, rounds=60, n_train=12000, n_test=2000, seed=0,
                                 async_cfg=async_cfg, fault_cfg=fault_cfg,
                                 defense=defense_cfg, link_cfg=link_cfg,
                                 hierarchy=hierarchy_cfg,
-                                mobility=mobility_cfg, **kw)
+                                mobility=mobility_cfg,
+                                use_pallas_compression=pallas, **kw)
     return make, fl_cfg
 
 
@@ -422,6 +430,8 @@ if __name__ == "__main__":
                          "mesh divisibility")
     ap.add_argument("--out", default="experiments/fl_results.json")
     a = ap.parse_args()
+    from benchmarks._harness import use_compile_cache
+    use_compile_cache()
     mesh = None
     if a.shard_clients:
         from repro.sharding import make_clients_mesh

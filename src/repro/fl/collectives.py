@@ -58,7 +58,6 @@ def make_sparse_fl_allreduce(mesh, gamma: float, *, vec_spec: Optional[P] = None
     paper's gamma*S + I payload expressed as an ICI collective
     (EXPERIMENTS.md §Perf-3 carries the ring-algorithm accounting too).
     """
-    from jax.experimental.shard_map import shard_map
     import math
 
     vec_spec = vec_spec if vec_spec is not None else P(("data", "model"))
@@ -87,10 +86,10 @@ def make_sparse_fl_allreduce(mesh, gamma: float, *, vec_spec: Optional[P] = None
             dense = dense.at[jnp.arange(nb)[:, None], all_idx[pth]].add(all_vals[pth])
         return (dense / n_pods).reshape(n).astype(vec.dtype)
 
-    # check_rep=False: the output IS pod-replicated (built from all-gathered
+    # check_vma=False: the output IS pod-replicated (built from all-gathered
     # data) but the static analysis cannot infer it through the scatter-adds
-    fn = shard_map(body, mesh=mesh, in_specs=(vec_spec,), out_specs=vec_spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(vec_spec,),
+                       out_specs=vec_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -101,13 +100,12 @@ def make_fl_allreduce(mesh, gamma: float, *, vec_spec: Optional[P] = None,
     The vector is sharded over the intra-silo axes; each silo compresses
     its shard locally (block-local top-k commutes with sharding when the
     shard size is a multiple of the block)."""
-    from jax.experimental.shard_map import shard_map
-
     vec_spec = vec_spec if vec_spec is not None else P(("data", "model"))
 
     def body(vec):
         sparse, _ = block_topk(vec, gamma, block=block)
         return jax.lax.pmean(sparse, "pod")
 
-    fn = shard_map(body, mesh=mesh, in_specs=(vec_spec,), out_specs=vec_spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(vec_spec,),
+                       out_specs=vec_spec)
     return jax.jit(fn)

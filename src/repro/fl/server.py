@@ -1043,7 +1043,6 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
     if not sharded:
         return scan_body
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     def scan_fn(params, ctrl_state, battery, astate, fstate, lstate, data,
@@ -1052,21 +1051,21 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
         # only `data` and the stale-update buffer are split (leading
         # client axis); everything else — params, controller state,
         # battery, defense state, link state, keys, round bounds, stacked
-        # logs — is replicated. check_rep=False: the outputs *are*
+        # logs — is replicated. check_vma=False: the outputs *are*
         # replicated (built from psum/all-gather results) but the static
-        # replication checker cannot see that through the scan carry.
+        # varying-axes checker cannot see that through the scan carry.
         ast_specs = async_state_specs(astate, axis)
         fst_specs = defense_state_specs(fstate)
         lst_specs = link_state_specs(lstate)
         data_entry = axes[0] if len(axes) == 1 else tuple(axes)
-        sharded_fn = shard_map(
+        sharded_fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(replicated_specs(params), replicated_specs(ctrl_state),
                       PS(), ast_specs, fst_specs, lst_specs, PS(data_entry),
                       PS(), PS(), PS(), PS()),
             out_specs=(replicated_specs(params), replicated_specs(ctrl_state),
                        PS(), ast_specs, fst_specs, lst_specs, PS()),
-            check_rep=False)
+            check_vma=False)
         return sharded_fn(params, ctrl_state, battery, astate, fstate,
                           lstate, data, keys, start_round, last_round,
                           eval_every)
@@ -1761,6 +1760,21 @@ class FederatedTrainer:
                       f"acc={lg.accuracy:.4f} sel={lg.n_selected:2d} "
                       f"E={lg.total_energy*1e3:.3f} mJ")
         return self.history
+
+    def lower_scanned(self, rounds: int, *, eval_every: int = 1):
+        """The fused engine's program for one ``rounds``-round chunk from
+        round 0 at the current carry, lowered but not run: ``.compile()``
+        gives its compile time, ``.as_text()`` the program (e.g. to find
+        the Pallas kernels, ``tpu_custom_call``) and its cost and memory
+        analyses. Runs the one-shot calibration first, as ``run_scanned``
+        would, so the program is the one that ``run_scanned(rounds)``
+        executes. Donates nothing."""
+        self._maybe_calibrate(0)
+        return self._get_scan_engine().lower(
+            self.params, self.ctrl_state, self._battery, self._astate,
+            self._fstate, self._lstate, self._data, self._keys(),
+            jnp.int32(0), jnp.int32(rounds - 1), jnp.int32(eval_every),
+            n_rounds=rounds)
 
     # ------------------------------------------------------- checkpointing ----
     def _carry_tree(self) -> dict:

@@ -15,8 +15,9 @@ inner iteration, so it must be an operand, not a compile-time constant.
 The gamma grid and Newton iteration count are static (baked via
 functools.partial), mirroring ``topk_sparsify``'s static-k layout.
 
-Grid: one program per client block. Block size must be a multiple of
-128 lanes (default 128; inputs are padded by ops.py).
+Grid: one program per (8, 128) tile of clients — eight 128-lane rows, the
+native f32 VMEM tile, so the block lowers on the chip at any N (inputs
+are padded to whole tiles by ops.py).
 """
 from __future__ import annotations
 
@@ -29,13 +30,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ref import _channel, ln_k_gamma_free, newton_snr
 
+# client rows per grid step: with 128-lane rows, one (8, 128) f32 tile
+ROWS = 8
+
 # scalar-prefetch vector layout
 N_SCALARS = 7
 (S_LAM, S_ETA, S_BTOT, S_SBITS, S_IBITS, S_N0, S_BLO) = range(N_SCALARS)
 
 def _best_response_block(P, h, u, ec, sc, *, gamma_grid, newton_iters,
                          es=None):
-    """Shared kernel body math on loaded [1, BLK] values. ``sc`` indexes
+    """Shared kernel body math on loaded [ROWS, BLK] values. ``sc`` indexes
     the scalar vector; ``ec`` is the per-client computation energy block
     (zeros for the communication-only objective); ``es`` the optional
     per-client outage pricing factor (``repro.core.link``), which scales
@@ -201,6 +205,31 @@ def _dual_solve_kernel_joint_scaled(sc_ref, p_ref, h_ref, u_ref, ec_ref,
     bits_ref[...] = bits
 
 
+def _row_tiled_call(kern, operands, scalars, *, n_out, block, interpret):
+    """Run ``kern`` over [n] client vectors laid out as [n/block, block]
+    rows, ``ROWS`` rows (one (ROWS, block) VMEM tile) per grid step, with
+    the scalar vector prefetched into SMEM. ``n`` must be a multiple of
+    ``ROWS * block``: the tile then satisfies the chip's (8, 128) rule."""
+    n = operands[0].shape[0]
+    assert n % (ROWS * block) == 0 and scalars.shape == (N_SCALARS,), \
+        (n, block, scalars.shape)
+    nr = n // block
+    tile = pl.BlockSpec((ROWS, block), lambda i, sc: (i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nr // ROWS,),
+        in_specs=[tile] * len(operands),
+        out_specs=[tile] * n_out,
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((nr, block), jnp.float32)] * n_out,
+        interpret=interpret,
+    )(scalars.astype(jnp.float32), *(x.reshape(nr, block) for x in operands))
+    return tuple(o.reshape(-1) for o in out)
+
+
 @functools.partial(jax.jit, static_argnames=("levels", "newton_iters",
                                              "block", "interpret"))
 def dual_solve_pallas_joint(P: jnp.ndarray, h: jnp.ndarray,
@@ -208,35 +237,18 @@ def dual_solve_pallas_joint(P: jnp.ndarray, h: jnp.ndarray,
                             scalars: jnp.ndarray,
                             e_scale: jnp.ndarray = None, *,
                             levels: tuple, newton_iters: int = 3,
-                            block: int = 128, interpret: bool = True):
+                            block: int = 128, interpret: bool = False):
     """Joint-grid twin of ``dual_solve_pallas``: ``levels`` is the static
     flat (gamma, bits) tuple from ``ref.joint_levels``; returns
     (gamma*, b*, e*, phi*, bits*), each [n]."""
-    n = P.shape[0]
-    assert n % block == 0 and scalars.shape == (N_SCALARS,), \
-        (P.shape, scalars.shape)
-    nb = n // block
-    rows = lambda x: x.reshape(nb, block)
-    blk = pl.BlockSpec((1, block), lambda i, sc: (i, 0))
-    n_in = 4 if e_scale is None else 5
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[blk] * n_in,
-        out_specs=[blk] * 5,
-    )
     kern = (_dual_solve_kernel_joint if e_scale is None
             else _dual_solve_kernel_joint_scaled)
-    operands = [rows(P), rows(h), rows(u_norms), rows(e_cmp)]
+    operands = [P, h, u_norms, e_cmp]
     if e_scale is not None:
-        operands.append(rows(e_scale))
-    out = pl.pallas_call(
+        operands.append(e_scale)
+    return _row_tiled_call(
         functools.partial(kern, levels=levels, newton_iters=newton_iters),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.float32)] * 5,
-        interpret=interpret,
-    )(scalars.astype(jnp.float32), *operands)
-    return tuple(o.reshape(-1) for o in out)
+        operands, scalars, n_out=5, block=block, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("gamma_grid", "newton_iters",
@@ -245,35 +257,18 @@ def dual_solve_pallas(P: jnp.ndarray, h: jnp.ndarray, u_norms: jnp.ndarray,
                       e_cmp: jnp.ndarray, scalars: jnp.ndarray,
                       e_scale: jnp.ndarray = None, *,
                       gamma_grid: tuple, newton_iters: int = 3,
-                      block: int = 128, interpret: bool = True):
-    """P/h/u_norms/e_cmp: [n] with n % block == 0; scalars: [N_SCALARS]
-    f32 (see the S_* layout). ``e_cmp`` is the per-client computation
-    energy (zeros => communication-only); ``e_scale`` the optional [n]
-    outage pricing factor (None selects the legacy 4-input kernel, and
-    the None/array split keys separate jit traces). Returns (gamma*, b*,
-    e*, phi*), each [n]."""
-    n = P.shape[0]
-    assert n % block == 0 and scalars.shape == (N_SCALARS,), \
-        (P.shape, scalars.shape)
-    nb = n // block
-    rows = lambda x: x.reshape(nb, block)
-    blk = pl.BlockSpec((1, block), lambda i, sc: (i, 0))
-    n_in = 4 if e_scale is None else 5
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[blk] * n_in,
-        out_specs=[blk, blk, blk, blk],
-    )
+                      block: int = 128, interpret: bool = False):
+    """P/h/u_norms/e_cmp: [n] with n % (ROWS * block) == 0; scalars:
+    [N_SCALARS] f32 (see the S_* layout). ``e_cmp`` is the per-client
+    computation energy (zeros => communication-only); ``e_scale`` the
+    optional [n] outage pricing factor (None selects the legacy 4-input
+    kernel, and the None/array split keys separate jit traces). Returns
+    (gamma*, b*, e*, phi*), each [n]."""
     kern = _dual_solve_kernel if e_scale is None else _dual_solve_kernel_scaled
-    operands = [rows(P), rows(h), rows(u_norms), rows(e_cmp)]
+    operands = [P, h, u_norms, e_cmp]
     if e_scale is not None:
-        operands.append(rows(e_scale))
-    out = pl.pallas_call(
+        operands.append(e_scale)
+    return _row_tiled_call(
         functools.partial(kern, gamma_grid=gamma_grid,
                           newton_iters=newton_iters),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.float32)] * 4,
-        interpret=interpret,
-    )(scalars.astype(jnp.float32), *operands)
-    return tuple(o.reshape(-1) for o in out)
+        operands, scalars, n_out=4, block=block, interpret=interpret)

@@ -1,19 +1,14 @@
 """jit'd public wrapper around the dual_solve Pallas kernel."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
-from .kernel import (N_SCALARS, S_BLO, S_BTOT, S_ETA, S_IBITS, S_LAM, S_N0,
-                     S_SBITS, dual_solve_pallas, dual_solve_pallas_joint)
+from .. import interpret_mode
+from .kernel import (N_SCALARS, ROWS, S_BLO, S_BTOT, S_ETA, S_IBITS, S_LAM,
+                     S_N0, S_SBITS, dual_solve_pallas, dual_solve_pallas_joint)
 from .ref import joint_levels
 
-# interpret=True executes the kernel body on CPU; on a real TPU runtime set
-# REPRO_PALLAS_INTERPRET=0 (ops read it once at import).
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-BLOCK = 128
+BLOCK = 128            # lanes of one client row
 
 
 def dual_solve(P: jnp.ndarray, h: jnp.ndarray, u_norms: jnp.ndarray,
@@ -30,12 +25,12 @@ def dual_solve(P: jnp.ndarray, h: jnp.ndarray, u_norms: jnp.ndarray,
     ``bits_grid`` (static tuple, optional) routes to the joint
     (gamma, bits) kernel pair, which returns a fifth ``bits*`` output;
     ``None`` keeps the legacy gamma-only kernels and the 4-tuple. Pads
-    the client axis to the 128-lane block and truncates the outputs
-    back."""
+    the client axis to whole (8, 128) tiles and truncates the outputs
+    back. Runs the kernel compiled on a TPU and interpreted on the CPU."""
     n = P.shape[0]
     if e_cmp is None:
         e_cmp = jnp.zeros((n,), jnp.float32)
-    pad = (-n) % BLOCK
+    pad = (-n) % (ROWS * BLOCK)
     if pad:
         # padded lanes must stay finite through log/Newton: unit channel,
         # zero score/comp, unit pricing factor (it runs through a log).
@@ -58,9 +53,9 @@ def dual_solve(P: jnp.ndarray, h: jnp.ndarray, u_norms: jnp.ndarray,
     if bits_grid is None:
         gam, b, e, phi = dual_solve_pallas(
             *args, gamma_grid=tuple(gamma_grid), newton_iters=newton_iters,
-            block=BLOCK, interpret=INTERPRET)
+            block=BLOCK, interpret=interpret_mode())
         return gam[:n], b[:n], e[:n], phi[:n]
     gam, b, e, phi, bits = dual_solve_pallas_joint(
         *args, levels=joint_levels(gamma_grid, bits_grid),
-        newton_iters=newton_iters, block=BLOCK, interpret=INTERPRET)
+        newton_iters=newton_iters, block=BLOCK, interpret=interpret_mode())
     return gam[:n], b[:n], e[:n], phi[:n], bits[:n]

@@ -61,7 +61,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            causal: bool = True, window: int | None = None,
                            bq: int = 256, bk: int = 256,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: [B, Sq, H, D]; k/v: [B, Skv, KV, D]; returns [B, Sq, H, D]."""
     B, Sq, H, D = q.shape
     _, Skv, KV, _ = k.shape

@@ -17,20 +17,25 @@ from jax.experimental import pallas as pl
 
 def _sq_sum_kernel(x_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)
-    out_ref[0] = jnp.sum(x * x)
+    out_ref[...] = jnp.full(out_ref.shape, jnp.sum(x * x), jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def sq_sum_partials(vec: jnp.ndarray, *, block: int = 65536,
-                    interpret: bool = True) -> jnp.ndarray:
-    assert vec.ndim == 1 and vec.shape[0] % block == 0
+                    interpret: bool = False) -> jnp.ndarray:
+    """[nb] per-block sums of squares of ``vec`` ([nb * block]). Each block
+    is laid out as an (8, block/8) VMEM tile and each partial lands in its
+    own (1, 1) output block, so both obey the chip's (8, 128) tiling rule
+    (block/8 a multiple of 128, or the whole last dimension)."""
+    assert vec.ndim == 1 and vec.shape[0] % block == 0 and block % 8 == 0
     nb = vec.shape[0] // block
-    rows = vec.reshape(nb, block)
-    return pl.pallas_call(
+    tiles = vec.reshape(nb, 8, block // 8)
+    out = pl.pallas_call(
         _sq_sum_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb,), jnp.float32),
+        in_specs=[pl.BlockSpec((1, 8, block // 8), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, 1), jnp.float32),
         interpret=interpret,
-    )(rows)
+    )(tiles)
+    return out.reshape(nb)

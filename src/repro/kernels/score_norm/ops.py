@@ -1,13 +1,10 @@
 """jit'd wrapper: padded L2 norm via the Pallas partial-reduction kernel."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import sq_sum_partials
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def l2_norm(vec: jnp.ndarray, *, block: int = 65536) -> jnp.ndarray:
@@ -16,5 +13,5 @@ def l2_norm(vec: jnp.ndarray, *, block: int = 65536) -> jnp.ndarray:
     nb = -(-n // block)
     pad = nb * block - n
     v = jnp.concatenate([vec, jnp.zeros((pad,), vec.dtype)]) if pad else vec
-    partials = sq_sum_partials(v, block=block, interpret=INTERPRET)
+    partials = sq_sum_partials(v, block=block, interpret=interpret_mode())
     return jnp.sqrt(jnp.sum(partials))

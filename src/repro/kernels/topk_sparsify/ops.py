@@ -5,12 +5,8 @@ import math
 
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import topk_sparsify_pallas, topk_sparsify_rows_pallas
-
-# interpret=True executes the kernel body on CPU; on a real TPU runtime set
-# REPRO_PALLAS_INTERPRET=0 (ops read it once at import).
-import os
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def block_topk_sparsify(vec: jnp.ndarray, gamma: float, *, block: int = 4096
@@ -21,7 +17,7 @@ def block_topk_sparsify(vec: jnp.ndarray, gamma: float, *, block: int = 4096
     nb = -(-n // block)
     pad = nb * block - n
     v = jnp.concatenate([vec, jnp.zeros((pad,), vec.dtype)]) if pad else vec
-    out = topk_sparsify_pallas(v, k=k, block=block, interpret=INTERPRET)
+    out = topk_sparsify_pallas(v, k=k, block=block, interpret=interpret_mode())
     return out[:n], k
 
 
@@ -29,4 +25,4 @@ def block_topk_sparsify_rows(rows: jnp.ndarray, ks: jnp.ndarray) -> jnp.ndarray:
     """rows: [R, block]; ks: [R] traced int32 — per-row dynamic k. Same
     keep rule as ``block_topk_sparsify`` but jittable with heterogeneous
     compression ratios (one row per client-block in the round engine)."""
-    return topk_sparsify_rows_pallas(rows, ks, interpret=INTERPRET)
+    return topk_sparsify_rows_pallas(rows, ks, interpret=interpret_mode())

@@ -22,13 +22,42 @@ import jax.numpy as jnp
 Array = jnp.ndarray
 
 
-def topk_threshold_mask(x: Array, k: Array) -> Array:
+def _first_n_mask(flags: Array, n: Array) -> Array:
+    """Mask of the first ``n`` set ``flags`` per row (index order) — the
+    same as ``cumsum(flags) <= n`` where ``n <= sum(flags)``, without a
+    prefix sum, which the chip's Pallas lowering lacks. Bisects on the
+    cut index j: the smallest j with ``count(flags[:j]) >= n``; then
+    ``index < j`` covers exactly the first n flags."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, flags.shape, flags.ndim - 1)
+    flags = flags.astype(jnp.int32)
+    width = flags.shape[-1]
+
+    # invariant: count(flags[:lo]) < n <= count(flags[:hi])
+    def body(_, lohi):
+        lo, hi = lohi
+        mid = lo + (hi - lo) // 2
+        enough = jnp.sum(jnp.where(idx < mid, flags, 0), axis=-1,
+                         keepdims=True) >= n
+        return jnp.where(enough, lo, mid), jnp.where(enough, mid, hi)
+
+    lo = jnp.zeros_like(n)
+    hi = jnp.full_like(n, width)
+    _, hi = jax.lax.fori_loop(0, max(1, (width - 1).bit_length()), body,
+                              (lo, hi))
+    return idx < hi
+
+
+def topk_threshold_mask(x: Array, k: Array, *,
+                        prefix_sum: bool = True) -> Array:
     """Keep-mask of the top-k magnitudes per row, ties to the lower index.
 
     x: [..., block] float; k: int32 broadcastable to [..., 1] (clipped by
     the caller to [1, block]). Matches the exact-sort oracle bit-for-bit:
     the k-th largest |x| is found by integer bisection on the fp32 bit
-    pattern, which is monotone for non-negative floats.
+    pattern, which is monotone for non-negative floats. Ties at the
+    threshold are filled in index order with a prefix sum, or, with
+    ``prefix_sum=False`` (the Pallas kernels), with the equivalent
+    index bisection of ``_first_n_mask``.
     """
     mag = jnp.abs(x.astype(jnp.float32))
     bits = jax.lax.bitcast_convert_type(mag, jnp.int32)      # >= 0 for |x|
@@ -50,7 +79,10 @@ def topk_threshold_mask(x: Array, k: Array) -> Array:
     greater = mag > thresh
     n_greater = jnp.sum(greater.astype(jnp.int32), axis=-1, keepdims=True)
     equal = mag == thresh
-    fill = jnp.cumsum(equal.astype(jnp.int32), axis=-1) <= (k - n_greater)
+    if prefix_sum:
+        fill = jnp.cumsum(equal.astype(jnp.int32), axis=-1) <= (k - n_greater)
+    else:
+        fill = _first_n_mask(equal, k - n_greater)
     return greater | (equal & fill)
 
 

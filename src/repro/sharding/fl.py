@@ -35,7 +35,7 @@ from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 CLIENTS_AXIS = "clients"
 CLUSTERS_AXIS = "clusters"
@@ -65,14 +65,16 @@ def make_clients_mesh(n_devices: Optional[int] = None,
     """1-D mesh over ``n_devices`` (default: all visible devices) with a
     single ``clients`` axis. On CPU, force multiple host devices with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=K`` before importing
-    jax."""
+    jax. The axis is ``Auto``: the engine places the client axis itself
+    (``shard_map`` and ``device_put``), and eager code outside it must see
+    ordinary arrays, not explicitly sharded ones."""
     n = n_devices if n_devices is not None else len(jax.devices())
     if n < 1:
         raise ValueError(f"need at least one device, got {n}")
     if n > len(jax.devices()):
         raise ValueError(f"requested {n} devices but only "
                          f"{len(jax.devices())} are visible")
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def make_hierarchy_mesh(n_clusters: Optional[int] = None,
@@ -94,7 +96,8 @@ def make_hierarchy_mesh(n_clusters: Optional[int] = None,
         raise ValueError(f"{n_clusters} clusters do not divide "
                          f"{n} devices")
     return jax.make_mesh((n_clusters, n // n_clusters),
-                         (clusters_axis, clients_axis))
+                         (clusters_axis, clients_axis),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_client_axes(mesh: Mesh, axis: AxisSpec = CLIENTS_AXIS) -> tuple:

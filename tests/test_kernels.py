@@ -43,7 +43,7 @@ def test_topk_with_ties():
 
 
 def test_topk_rows_dynamic_k_matches_ref():
-    """Pallas rows kernel (scalar-prefetched per-row k) and the jitted
+    """Pallas rows kernel (a per-row k column per tile) and the jitted
     bisection fast path both match the sort-based rows oracle."""
     from repro.fl.compression import _rows_topk_bisect
     rows = jax.random.normal(jax.random.PRNGKey(3), (12, 1024))

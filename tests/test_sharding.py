@@ -72,7 +72,7 @@ def test_dryrun_subprocess_smoke():
     """Run the real dryrun CLI for one cheap combo (spawns its own 512-dev
     placeholder backend)."""
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"      # placeholder host devices, never a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "whisper-tiny",

@@ -112,15 +112,16 @@ def test_sparse_crosspod_aggregation(monkeypatch):
     """Sparse (values+indices) cross-pod exchange == dense-masked psum."""
     import subprocess, sys, os
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"      # the 8 forced host devices, never a chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     code = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.fl.collectives import make_fl_allreduce, make_sparse_fl_allreduce
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 vec = jax.device_put(jnp.asarray(np.random.default_rng(0).normal(size=1<<16).astype(np.float32)),
                      NamedSharding(mesh, P(("data", "model"))))
 a = make_fl_allreduce(mesh, 0.25)(vec)
