@@ -482,7 +482,8 @@ def _make_round_core(*, controller: Controller, spec, weights: jnp.ndarray,
         obs = RoundObservation(u_norms=obs_norms, h=h_obs, P=P, round=r,
                                key=key, alive=alive, t_round=t_obs,
                                e_scale=e_scale)
-        dec, new_state = controller.decide(obs, ctrl_state)
+        with jax.named_scope("fl.decide"):
+            dec, new_state = controller.decide(obs, ctrl_state)
         if battery is not None:
             # hard mask, whatever the controller decided: a depleted
             # client transmits nothing and is charged nothing
@@ -698,9 +699,10 @@ def _make_round_core(*, controller: Controller, spec, weights: jnp.ndarray,
             w_data = jax.lax.dynamic_slice_in_dim(weights, i0, n_local)
         else:
             w_data = weights
-        sparse = compression.batch_block_topk(updates, gamma, block=block,
-                                              use_pallas=use_pallas,
-                                              skip_full=skip_full_sparsify)
+        with jax.named_scope("fl.sparsify"):
+            sparse = compression.batch_block_topk(
+                updates, gamma, block=block, use_pallas=use_pallas,
+                skip_full=skip_full_sparsify)
         if quant:
             # client-side symmetric fixed-point quantization of the
             # sparse payload at the transmitted width, dequantized right
@@ -726,44 +728,46 @@ def _make_round_core(*, controller: Controller, spec, weights: jnp.ndarray,
         # exactly the legacy weighted-mean ops; a defended aggregator
         # screens/clips/trims shard-local and returns the cleaned sparse
         # matrix (what the staleness buffer must hold) plus its stats
-        partial, wsum, fstate, dstats, sparse = agg_obj(
-            sparse, xf, w_data, fstate,
-            axis=ax_all if sharded else None,
-            n_shards=n_pad // n_local)                          # [D], scalar
-        if async_rt is not None and async_rt.staleness:
-            # ---- staleness-weighted buffered aggregation (shard-local):
-            # age the pending slots by this round's wall-clock, fold the
-            # ones whose background transmission has completed into the
-            # aggregate with the w(tau) discount, then buffer this
-            # round's late updates (one slot per client — a newer late
-            # update replaces an older, staler one)
-            buf, age, t_rem = astate
-            pending = age >= 0
-            age = jnp.where(pending, age + 1, age)
-            t_rem = jnp.where(pending, t_rem - extras["t_wall"], t_rem)
-            ready = pending & (t_rem <= 0.0)
-            w_stale = (w_data * staleness_weight(age, async_rt.staleness_a)
-                       * ready.astype(jnp.float32))
-            wsum = wsum + jnp.sum(w_stale)
-            partial = partial + w_stale @ buf
-            late_l = _local(late.astype(jnp.float32), 0.0, i0, n_local) > 0.0 \
-                if sharded else late
-            t_new = jnp.clip(t_total - async_rt.deadline, 0.0, None)
-            t_new_l = _local(t_new, 0.0, i0, n_local) if sharded else t_new
-            buf = jnp.where(late_l[:, None], sparse, buf)
-            age = jnp.where(late_l, 0, jnp.where(ready, -1, age))
-            t_rem = jnp.where(late_l, t_new_l,
-                              jnp.where(ready, 0.0, t_rem))
-            astate = AsyncState(buf=buf, age=age, t_rem=t_rem)
-            n_stale = jnp.sum(ready.astype(jnp.int32))
+        with jax.named_scope("fl.aggregate"):
+            partial, wsum, fstate, dstats, sparse = agg_obj(
+                sparse, xf, w_data, fstate,
+                axis=ax_all if sharded else None,
+                n_shards=n_pad // n_local)                  # [D], scalar
+            if async_rt is not None and async_rt.staleness:
+                # ---- staleness-weighted buffered aggregation
+                # (shard-local): age the pending slots by this round's
+                # wall-clock, fold the ones whose background transmission
+                # has completed into the aggregate with the w(tau)
+                # discount, then buffer this round's late updates (one
+                # slot per client — a newer late update replaces an
+                # older, staler one)
+                buf, age, t_rem = astate
+                pending = age >= 0
+                age = jnp.where(pending, age + 1, age)
+                t_rem = jnp.where(pending, t_rem - extras["t_wall"], t_rem)
+                ready = pending & (t_rem <= 0.0)
+                w_stale = (w_data * staleness_weight(age, async_rt.staleness_a)
+                           * ready.astype(jnp.float32))
+                wsum = wsum + jnp.sum(w_stale)
+                partial = partial + w_stale @ buf
+                late_l = (_local(late.astype(jnp.float32), 0.0, i0,
+                                 n_local) > 0.0 if sharded else late)
+                t_new = jnp.clip(t_total - async_rt.deadline, 0.0, None)
+                t_new_l = _local(t_new, 0.0, i0, n_local) if sharded else t_new
+                buf = jnp.where(late_l[:, None], sparse, buf)
+                age = jnp.where(late_l, 0, jnp.where(ready, -1, age))
+                t_rem = jnp.where(late_l, t_new_l,
+                                  jnp.where(ready, 0.0, t_rem))
+                astate = AsyncState(buf=buf, age=age, t_rem=t_rem)
+                n_stale = jnp.sum(ready.astype(jnp.int32))
+                if sharded:
+                    n_stale = _psum_stages(n_stale)
+                extras["n_stale"] = n_stale
             if sharded:
-                n_stale = _psum_stages(n_stale)
-            extras["n_stale"] = n_stale
-        if sharded:
-            wsum = _psum_stages(wsum)
-            partial = _psum_stages(partial)
-        agg = partial / jnp.maximum(wsum, 1e-12) * server_lr
-        agg = jnp.where(wsum > 0.0, agg, jnp.zeros_like(agg))
+                wsum = _psum_stages(wsum)
+                partial = _psum_stages(partial)
+            agg = partial / jnp.maximum(wsum, 1e-12) * server_lr
+            agg = jnp.where(wsum > 0.0, agg, jnp.zeros_like(agg))
         if telemetry:
             n_part = jnp.sum(part_glob.astype(jnp.int32))
             n_rej = dstats.get("n_rejected", jnp.int32(0))
@@ -789,9 +793,10 @@ def _make_round_core(*, controller: Controller, spec, weights: jnp.ndarray,
             fextras = dict(
                 n_faulted=nf, n_rejected=n_rej, clip_frac=clip_frac,
                 fallback=jnp.asarray(dec.fallback, jnp.bool_))
-        delta_tree = unflatten_update(agg, spec)
-        new_params = jax.tree_util.tree_map(
-            lambda p, d: p + d.astype(p.dtype), params, delta_tree)
+        with jax.named_scope("fl.aggregate"):
+            delta_tree = unflatten_update(agg, spec)
+            new_params = jax.tree_util.tree_map(
+                lambda p, d: p + d.astype(p.dtype), params, delta_tree)
         if quant:
             # e_saved counterfactual: what the same (gamma, B) allocation
             # would have cost at a full 32-bit payload minus the realized
@@ -974,17 +979,22 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
 
         def step(carry, r):
             p, state, batt, ast, fst, lst = carry
-            h = round_gains(keys["fade"], pathloss, r, rayleigh,
-                            mobility=mobility)
-            # every shard derives the full (tiny) per-client key set —
-            # real clients keep the unpadded split stream — and slices
-            # its local chunk: identical batches in every layout
-            ckeys = jax.lax.dynamic_slice_in_dim(
-                client_sample_keys(keys["sample"], r, n_real_keys,
-                                   n_pad_keys), i0, n_local)
-            batches = sample_client_batches(data.arrays, data.lengths, ckeys,
-                                            local_steps, batch)
-            updates, u_norms, losses = client_step(p, batches)
+            # the round's stages are named (``fl.<stage>``) in the HLO
+            # metadata, so a profiler trace attributes every device op
+            with jax.named_scope("fl.sample"):
+                h = round_gains(keys["fade"], pathloss, r, rayleigh,
+                                mobility=mobility)
+                # every shard derives the full (tiny) per-client key set
+                # — real clients keep the unpadded split stream — and
+                # slices its local chunk: identical batches in every
+                # layout
+                ckeys = jax.lax.dynamic_slice_in_dim(
+                    client_sample_keys(keys["sample"], r, n_real_keys,
+                                       n_pad_keys), i0, n_local)
+                batches = sample_client_batches(data.arrays, data.lengths,
+                                                ckeys, local_steps, batch)
+            with jax.named_scope("fl.client_step"):
+                updates, u_norms, losses = client_step(p, batches)
             ckey = jax.random.fold_in(keys["ctrl"], r)
             if linky:
                 p, dec, state, batt, ast, fst, lst, extras = core(
@@ -1009,9 +1019,10 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
                 losses = jax.lax.all_gather(
                     losses, axis, tiled=True)[:n_real]
             do_eval = ((r % eval_every) == 0) | (r == last_round)
-            acc = jax.lax.cond(do_eval,
-                               lambda q: eval_fn(q).astype(jnp.float32),
-                               lambda q: jnp.float32(jnp.nan), p)
+            with jax.named_scope("fl.eval"):
+                acc = jax.lax.cond(do_eval,
+                                   lambda q: eval_fn(q).astype(jnp.float32),
+                                   lambda q: jnp.float32(jnp.nan), p)
             out = dict(x=dec.x, gamma=dec.gamma, bandwidth=dec.bandwidth,
                        energy=dec.energy, accuracy=acc,
                        loss=jnp.mean(losses), battery=batt)
@@ -1647,11 +1658,12 @@ class FederatedTrainer:
         """
         self._maybe_calibrate(r)
         engine = self._get_scan_engine()
-        (self.params, self.ctrl_state, self._battery, self._astate,
-         self._fstate, self._lstate, outs) = engine(
-            self.params, self.ctrl_state, self._battery, self._astate,
-            self._fstate, self._lstate, self._data, self._keys(), jnp.int32(r),
-            jnp.int32(r), jnp.int32(1), n_rounds=1)
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            (self.params, self.ctrl_state, self._battery, self._astate,
+             self._fstate, self._lstate, outs) = engine(
+                self.params, self.ctrl_state, self._battery, self._astate,
+                self._fstate, self._lstate, self._data, self._keys(),
+                jnp.int32(r), jnp.int32(r), jnp.int32(1), n_rounds=1)
         self._append_chunk_logs(r, outs)
         return self.history[-1]
 
@@ -1675,34 +1687,37 @@ class FederatedTrainer:
     def _append_chunk_logs(self, start: int, outs) -> None:
         """Materialize one chunk of stacked scan outputs (single host
         sync) into per-round ``RoundLog``s."""
-        host = {k: np.asarray(v) for k, v in outs.items()}
+        with jax.profiler.TraceAnnotation("fl.sync"):
+            # waits for the device, then copies the chunk to the host
+            host = {k: np.asarray(v) for k, v in outs.items()}
         timed = "t_round" in host
         faulted = "n_faulted" in host
         linked = "n_retx" in host
         quanted = "bits" in host
-        for i in range(host["x"].shape[0]):
-            x = host["x"][i]
-            self.history.append(RoundLog(
-                round=start + i, selected=x, gamma=host["gamma"][i],
-                bandwidth=host["bandwidth"][i], energy=host["energy"][i],
-                accuracy=float(host["accuracy"][i]),
-                loss=float(host["loss"][i]), n_selected=int(x.sum()),
-                battery=host["battery"][i] if "battery" in host else None,
-                t_round=float(host["t_round"][i]) if timed else None,
-                made=host["made"][i] if timed else None,
-                n_late=int(host["n_late"][i]) if timed else None,
-                n_stale=int(host["n_stale"][i]) if timed else None,
-                n_faulted=int(host["n_faulted"][i]) if faulted else None,
-                n_rejected=int(host["n_rejected"][i]) if faulted else None,
-                clip_frac=float(host["clip_frac"][i]) if faulted else None,
-                fallback=bool(host["fallback"][i]) if faulted else None,
-                n_retx=int(host["n_retx"][i]) if linked else None,
-                n_outage=int(host["n_outage"][i]) if linked else None,
-                goodput_frac=(float(host["goodput_frac"][i])
-                              if linked else None),
-                e_retx=float(host["e_retx"][i]) if linked else None,
-                bits=host["bits"][i] if quanted else None,
-                e_saved=float(host["e_saved"][i]) if quanted else None))
+        with jax.profiler.TraceAnnotation("fl.logs"):
+            for i in range(host["x"].shape[0]):
+                x = host["x"][i]
+                self.history.append(RoundLog(
+                    round=start + i, selected=x, gamma=host["gamma"][i],
+                    bandwidth=host["bandwidth"][i], energy=host["energy"][i],
+                    accuracy=float(host["accuracy"][i]),
+                    loss=float(host["loss"][i]), n_selected=int(x.sum()),
+                    battery=host["battery"][i] if "battery" in host else None,
+                    t_round=float(host["t_round"][i]) if timed else None,
+                    made=host["made"][i] if timed else None,
+                    n_late=int(host["n_late"][i]) if timed else None,
+                    n_stale=int(host["n_stale"][i]) if timed else None,
+                    n_faulted=int(host["n_faulted"][i]) if faulted else None,
+                    n_rejected=int(host["n_rejected"][i]) if faulted else None,
+                    clip_frac=float(host["clip_frac"][i]) if faulted else None,
+                    fallback=bool(host["fallback"][i]) if faulted else None,
+                    n_retx=int(host["n_retx"][i]) if linked else None,
+                    n_outage=int(host["n_outage"][i]) if linked else None,
+                    goodput_frac=(float(host["goodput_frac"][i])
+                                  if linked else None),
+                    e_retx=float(host["e_retx"][i]) if linked else None,
+                    bits=host["bits"][i] if quanted else None,
+                    e_saved=float(host["e_saved"][i]) if quanted else None))
 
     def run_scanned(self, rounds: Optional[int] = None, *,
                     chunk: Optional[int] = None, eval_every: int = 1,
@@ -1745,11 +1760,13 @@ class FederatedTrainer:
         keys = self._keys()
         for ci, s in enumerate(range(start_round, rounds, chunk)):
             n = min(chunk, rounds - s)
-            (self.params, self.ctrl_state, self._battery, self._astate,
-             self._fstate, self._lstate, outs) = engine(
-                self.params, self.ctrl_state, self._battery, self._astate,
-                self._fstate, self._lstate, self._data, keys, jnp.int32(s),
-                jnp.int32(rounds - 1), jnp.int32(eval_every), n_rounds=n)
+            with jax.profiler.TraceAnnotation("fl.dispatch"):
+                (self.params, self.ctrl_state, self._battery, self._astate,
+                 self._fstate, self._lstate, outs) = engine(
+                    self.params, self.ctrl_state, self._battery,
+                    self._astate, self._fstate, self._lstate, self._data,
+                    keys, jnp.int32(s), jnp.int32(rounds - 1),
+                    jnp.int32(eval_every), n_rounds=n)
             self._append_chunk_logs(s, outs)
             if ckpt_dir is not None and ((ci + 1) % ckpt_every == 0
                                          or s + n >= rounds):

@@ -47,6 +47,19 @@ def _first_n_mask(flags: Array, n: Array) -> Array:
     return idx < hi
 
 
+def _prefix_count(flags: Array) -> Array:
+    """``jnp.cumsum(flags, axis=-1)`` in int32, spelled as the
+    reduce-window that ``cumsum`` lowers to. ``cumsum`` emits it as an
+    outlined function whose ops carry no name stack of the caller; this
+    one is lowered in place, so the caller's ``jax.named_scope`` names
+    it in the compiled program. Same op, same result."""
+    width = flags.shape[-1]
+    lead = (1,) * (flags.ndim - 1)
+    return jax.lax.reduce_window(
+        flags.astype(jnp.int32), 0, jax.lax.add, lead + (width,),
+        lead + (1,), [(0, 0)] * (flags.ndim - 1) + [(width - 1, 0)])
+
+
 def topk_threshold_mask(x: Array, k: Array, *,
                         prefix_sum: bool = True) -> Array:
     """Keep-mask of the top-k magnitudes per row, ties to the lower index.
@@ -80,7 +93,7 @@ def topk_threshold_mask(x: Array, k: Array, *,
     n_greater = jnp.sum(greater.astype(jnp.int32), axis=-1, keepdims=True)
     equal = mag == thresh
     if prefix_sum:
-        fill = jnp.cumsum(equal.astype(jnp.int32), axis=-1) <= (k - n_greater)
+        fill = _prefix_count(equal) <= (k - n_greater)
     else:
         fill = _first_n_mask(equal, k - n_greater)
     return greater | (equal & fill)
