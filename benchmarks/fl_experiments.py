@@ -34,12 +34,14 @@ def build(n_clients=20, rounds=60, n_train=12000, n_test=2000, seed=0,
           deadline=None, staleness_a=None, fault_rate=None, crash_rate=None,
           churn=None, defense=None, clusters=None, pool_frac=None,
           mobility_sigma=None, max_retx=None, burst_p=None,
-          price_outage=None, bits_grid=None, pallas=False):
-    """Trainer factory for the paper's setting. ``pallas`` routes the
-    solver and the top-k compression through the Pallas kernels
-    (``use_pallas_solver`` / ``use_pallas_compression``) instead of the
-    jnp paths. Returns ``(make, fl_cfg)``; ``make(controller, **kw)``
-    builds a ``FederatedTrainer``."""
+          price_outage=None, bits_grid=None, pallas=None):
+    """Trainer factory for the paper's setting. ``pallas=True`` routes
+    the solver and the top-k compression through the Pallas kernels
+    (``use_pallas_solver`` / ``use_pallas_compression``), ``False``
+    through the jnp paths; ``None`` (default) keeps the jnp solver and
+    picks the top-k by backend (the kernel on a TPU). Returns
+    ``(make, fl_cfg)``; ``make(controller, **kw)`` builds a
+    ``FederatedTrainer``."""
     cfg = CNN_FULL
     scn = get_scenario(scenario) if isinstance(scenario, str) else scenario
     beta = scn.beta(0.3) if scn else 0.3

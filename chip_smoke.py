@@ -12,13 +12,17 @@ makes it. Weights and data come from seed 0.
 Phases, one stdout line each:
 
   a  JAX sees a TPU. There is no CPU fallback: without one, exit non-zero.
-  b  a few rounds on the default jnp solver and compression paths.
-  c  the same trainer and seed with the Pallas solver and top-k kernels.
-     The compiled round program must hold the kernels (``tpu_custom_call``).
-     On identical round-0 inputs the kernels' decisions (selection, gamma,
-     bandwidth, energy) and sparsified rows must match the jnp path to fp32
-     tolerance, and the first round of the run must select the same clients
-     as phase b. Later rounds may drift apart through near-threshold ties.
+  b  a few rounds on the default paths: the jnp solver, and the top-k
+     chosen by backend, which on a TPU is the Pallas kernel over the [N, D]
+     update matrix. The compiled round program must hold it
+     (``tpu_custom_call``).
+  c  the same trainer and seed with the Pallas solver as well. On
+     identical round-0 inputs the solver kernel's decisions (selection,
+     gamma, bandwidth, energy) must match the jnp solver's to fp32
+     tolerance, the default top-k's sparsified rows must be the jnp top-k's
+     (``use_pallas=False``) bit for bit, and the first round of the run
+     must select the same clients as phase b. Later rounds may drift apart
+     through near-threshold ties.
   d  accuracy, energies and params are finite and every round selects a
      client.
 
@@ -153,20 +157,25 @@ def _compare_kernels(tr_jnp, tr_pl, updates, obs, state) -> dict:
         np.testing.assert_allclose(np.asarray(getattr(dec_p, name)),
                                    np.asarray(getattr(dec_j, name)),
                                    rtol=1e-4, atol=0, err_msg=name)
-    # every client sparsified at its decided gamma (k >= 1 per block)
+    # every client sparsified at its decided gamma (k >= 1 per block), by
+    # the default path (the kernel on a TPU) and by the jnp path
     gamma = jnp.clip(dec_j.gamma, 1e-6, 1.0)
     topk = jax.jit(batch_block_topk,
                    static_argnames=("use_pallas", "skip_full"))
+    _require(_kernel_calls(topk.lower(updates, gamma, skip_full=False)
+                           .compile()) > 0,
+             "no Pallas kernel in the default top-k")
     rows_j = topk(updates, gamma, use_pallas=False, skip_full=False)
-    rows_p = topk(updates, gamma, use_pallas=True, skip_full=False)
-    np.testing.assert_array_equal(np.asarray(rows_p), np.asarray(rows_j),
-                                  err_msg="sparsified rows")
+    rows_d = topk(updates, gamma, skip_full=False)
+    np.testing.assert_array_equal(np.asarray(rows_d).view(np.int32),
+                                  np.asarray(rows_j).view(np.int32),
+                                  err_msg="sparsified rows, default vs jnp")
     sel = np.asarray(dec_j.x)
     return {"round0_selected": int(sel.sum()),
             "bandwidth_max_rel_diff": _max_rel(dec_p.bandwidth,
                                                dec_j.bandwidth),
             "energy_max_rel_diff": _max_rel(dec_p.energy, dec_j.energy),
-            "rows_equal": True,
+            "rows_bits_equal": True,
             "rows_kept": int(np.count_nonzero(np.asarray(rows_j)))}
 
 
@@ -207,9 +216,11 @@ def one_chip(n_clients: int = 50, rounds: int = ROUNDS, **build_kw):
     tr_pl = make_pl("fairenergy")
     updates, obs, state = _round0_inputs(tr)
 
-    _, timing = _timed_runs(tr, rounds)
-    _emit("b", path="jnp", n_clients=n_clients, n_params=tr.n_params,
-          **timing, **cache.take())
+    compiled, timing = _timed_runs(tr, rounds)
+    n_calls = _kernel_calls(compiled)
+    _require(n_calls > 0, "no Pallas kernel in the default round program")
+    _emit("b", path="default", n_clients=n_clients, n_params=tr.n_params,
+          tpu_custom_calls=n_calls, **timing, **cache.take())
 
     kernels = _compare_kernels(tr, tr_pl, updates, obs, state)
     del updates
@@ -219,10 +230,11 @@ def one_chip(n_clients: int = 50, rounds: int = ROUNDS, **build_kw):
     np.testing.assert_array_equal(tr_pl.history[0].selected,
                                   tr.history[0].selected,
                                   err_msg="first-round selection, b vs c")
-    _emit("c", path="pallas", tpu_custom_calls=n_calls, **kernels,
+    _emit("c", path="pallas solver", tpu_custom_calls=n_calls, **kernels,
           first_round_mask_equal=True, **timing, **cache.take())
 
-    _emit("d", jnp=_check_finite(tr, "b"), pallas=_check_finite(tr_pl, "c"))
+    _emit("d", default=_check_finite(tr, "b"),
+          pallas_solver=_check_finite(tr_pl, "c"))
 
 
 def four_chips(devices, n_clients: int = 200, rounds: int = ROUNDS,

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,36 +69,48 @@ def _rows_topk_bisect(rows: Array, ks: Array) -> Array:
 
 
 def batch_block_topk(mat: Array, gamma: Array, block: int = DEFAULT_BLOCK,
-                     use_pallas: bool = False, skip_full: bool = True) -> Array:
+                     use_pallas: Optional[bool] = None,
+                     skip_full: bool = True) -> Array:
     """Per-client block top-k with *traced* per-client gamma.
 
     mat: [N, D] stacked flat updates; gamma: [N] compression ratios (may be
     traced, e.g. straight out of a jitted controller decision). Each
     client's row is sparsified to k = ceil(gamma_i * block) kept per block
-    — identical keep rule to ``block_topk`` — in a single fused call
-    ([N*nb, block] rows with a per-row k), so the whole
-    decide -> sparsify -> aggregate round stays one jitted program.
+    — identical keep rule to ``block_topk`` — in a single fused call with
+    a per-client k, so the whole decide -> sparsify -> aggregate round
+    stays one jitted program.
+
+    ``use_pallas`` picks the implementation; both give the same bits (the
+    same bisection, keep rule and pad zeros; ``ref.topk_keep``). ``None``
+    (default) picks by backend: on a TPU the Pallas kernel
+    ``topk_sparsify_matrix_pallas``, which reads ``mat``'s own tiles in
+    place; elsewhere the jnp bisection over the zero-padded
+    [N*nb, block] view. ``True`` forces the kernel (the interpreter on
+    the CPU), ``False`` the jnp path.
 
     ``skip_full`` (default): when *every* client's k equals the block
     (gamma = 1, i.e. full precision — ScoreMax/RandomFull/ChannelGreedy
-    rounds), the sparsify pass is an identity, so a ``lax.cond`` skips it
-    at runtime — ~40% of the round on the N=50 bench workload. (Under
-    ``vmap``, e.g. the seed sweep, the cond lowers to a select and both
-    branches run; the result is unchanged.)
+    rounds), the sparsify pass is an identity and is skipped at runtime:
+    by a ``lax.cond`` on the jnp path, inside the kernel on the kernel
+    path. (Under ``vmap``, e.g. the seed sweep, the cond lowers to a
+    select and both branches run, while the kernel skips per lane; the
+    result is unchanged.)
     """
     n, d = mat.shape
+    ks = jnp.clip(jnp.ceil(gamma * block).astype(jnp.int32), 1, block)   # [N]
+    skip = jnp.all(ks >= block) if skip_full else False
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if use_pallas:
+        from repro.kernels.topk_sparsify.ops import block_topk_sparsify_matrix
+        return block_topk_sparsify_matrix(mat, ks, skip, block=block)
     nb = -(-d // block)
     pad = nb * block - d
     rows = jnp.pad(mat, ((0, 0), (0, pad))).reshape(n * nb, block)
-    ks = jnp.clip(jnp.ceil(gamma * block).astype(jnp.int32), 1, block)   # [N]
     ks_rows = jnp.repeat(ks, nb)                                         # [N*nb]
-    if use_pallas:
-        from repro.kernels.topk_sparsify.ops import block_topk_sparsify_rows
-        sparsify = lambda r: block_topk_sparsify_rows(r, ks_rows)
-    else:
-        sparsify = lambda r: _rows_topk_bisect(r, ks_rows)
+    sparsify = lambda r: _rows_topk_bisect(r, ks_rows)                   # noqa: E731
     if skip_full:
-        out = jax.lax.cond(jnp.all(ks >= block), lambda r: r, sparsify, rows)
+        out = jax.lax.cond(skip, lambda r: r, sparsify, rows)
     else:
         out = sparsify(rows)
     return out.reshape(n, nb * block)[:, :d]

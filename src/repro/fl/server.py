@@ -247,7 +247,7 @@ class _QuantRuntime:
 
 
 def _make_round_core(*, controller: Controller, spec, weights: jnp.ndarray,
-                     server_lr: float, use_pallas: bool = False,
+                     server_lr: float, use_pallas: Optional[bool] = None,
                      block: int = compression.DEFAULT_BLOCK,
                      skip_full_sparsify: bool = True,
                      shard_axis: Optional[str] = None,
@@ -866,7 +866,7 @@ def _make_round_core(*, controller: Controller, spec, weights: jnp.ndarray,
 
 
 def make_round_engine(*, controller: Controller, spec, weights: jnp.ndarray,
-                      server_lr: float, use_pallas: bool = False,
+                      server_lr: float, use_pallas: Optional[bool] = None,
                       block: int = compression.DEFAULT_BLOCK,
                       skip_full_sparsify: bool = True,
                       fault_rt: Optional[_FaultsRuntime] = None,
@@ -882,7 +882,8 @@ def make_round_engine(*, controller: Controller, spec, weights: jnp.ndarray,
 def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
                      server_lr: float, client_step, eval_fn,
                      pathloss: jnp.ndarray, P: jnp.ndarray, rayleigh: bool,
-                     local_steps: int, batch: int, use_pallas: bool = False,
+                     local_steps: int, batch: int,
+                     use_pallas: Optional[bool] = None,
                      block: int = compression.DEFAULT_BLOCK, unroll: int = 1,
                      mesh=None, mesh_axis: str = CLIENTS_AXIS,
                      n_real: Optional[int] = None,
@@ -1167,6 +1168,11 @@ class FederatedTrainer:
     ``e_retx``). ``None`` — or a config with neither ``outage`` nor a
     bursty chain — compiles the exact legacy program, same goldens
     contract as ``fault_cfg``.
+
+    ``use_pallas_compression``: how the top-k runs, as
+    ``compression.batch_block_topk``'s ``use_pallas``: ``None`` (default)
+    by backend, the Pallas kernel on a TPU and the jnp bisection
+    elsewhere; ``True`` or ``False`` force one. Both give the same bits.
     """
 
     def __init__(self, *, model_loss, model_params, client_datasets,
@@ -1175,7 +1181,8 @@ class FederatedTrainer:
                  strategy: Optional[str] = None,
                  fixed_k: Optional[int] = None,
                  eco_gamma: float = 0.1, eco_bandwidth: Optional[float] = None,
-                 use_pallas_compression: bool = False, seed: int = 0,
+                 use_pallas_compression: Optional[bool] = None,
+                 seed: int = 0,
                  mesh=None, mesh_axis: str = CLIENTS_AXIS,
                  device_profile=None,
                  async_cfg: Optional[AsyncConfig] = None,

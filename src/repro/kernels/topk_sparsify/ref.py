@@ -6,11 +6,12 @@ coefficients are kept — those with the largest |x|, ties broken by index
 order (earlier index wins). Trailing padding (zeros) competes like any
 other value but the result is truncated back to the input length.
 
-``topk_threshold_mask`` is the shared sort-free implementation used by
-both the dynamic-k jnp fast path and the Pallas kernel bodies: it finds
-the exact k-th largest magnitude by bisecting on the fp32 *bit pattern*
-(non-negative floats order identically to their int32 bits, so 31 integer
-halvings pin the threshold exactly — no epsilon band, any dynamic range).
+``topk_threshold_mask`` (the dynamic-k jnp fast path) and ``topk_keep``
+(the Pallas kernel's body) are the shared sort-free implementation: both
+find the exact k-th largest magnitude by bisecting on the fp32 *bit
+pattern* (``_kth_magnitudes``: non-negative floats order identically to
+their int32 bits, so 31 integer halvings pin the threshold exactly — no
+epsilon band, any dynamic range).
 """
 from __future__ import annotations
 
@@ -60,43 +61,87 @@ def _prefix_count(flags: Array) -> Array:
         lead + (1,), [(0, 0)] * (flags.ndim - 1) + [(width - 1, 0)])
 
 
-def topk_threshold_mask(x: Array, k: Array, *,
-                        prefix_sum: bool = True) -> Array:
+def _kth_magnitudes(xs: list[Array], k: Array
+                    ) -> tuple[list[Array], list[Array], Array]:
+    """For blocks ``xs`` (each [..., block], one k per row for all): |x|
+    in float32 per block, the int32 bit pattern of each block's k-th
+    largest |x| per row ([..., 1]), and k broadcast to [..., 1].
+    Non-negative floats order as their int32 bits, so 31 integer halvings
+    pin the k-th value exactly, at any dynamic range. The blocks share
+    one loop: on the chip each pass's reductions are a chain of
+    dependent steps, and independent blocks fill its gaps."""
+    mags = [jnp.abs(x.astype(jnp.float32)) for x in xs]
+    bits = [jax.lax.bitcast_convert_type(m, jnp.int32) for m in mags]  # >= 0
+    k = jnp.broadcast_to(jnp.asarray(k, jnp.int32), mags[0].shape[:-1] + (1,))
+
+    # invariant: count(bits >= lo) >= k, count(bits >= hi) < k
+    los = [jnp.zeros_like(k) for _ in bits]
+    his = [jnp.max(b, axis=-1, keepdims=True) + 1 for b in bits]
+
+    def body(_, lohi):
+        new = [], []
+        for b, lo, hi in zip(bits, *lohi):
+            mid = lo + (hi - lo) // 2
+            enough = jnp.sum((b >= mid).astype(jnp.int32), axis=-1,
+                             keepdims=True) >= k
+            new[0].append(jnp.where(enough, mid, lo))
+            new[1].append(jnp.where(enough, hi, mid))
+        return new
+
+    los, _ = jax.lax.fori_loop(0, 31, body, (los, his))
+    return mags, los, k
+
+
+def topk_threshold_mask(x: Array, k: Array) -> Array:
     """Keep-mask of the top-k magnitudes per row, ties to the lower index.
 
     x: [..., block] float; k: int32 broadcastable to [..., 1] (clipped by
     the caller to [1, block]). Matches the exact-sort oracle bit-for-bit:
     the k-th largest |x| is found by integer bisection on the fp32 bit
-    pattern, which is monotone for non-negative floats. Ties at the
-    threshold are filled in index order with a prefix sum, or, with
-    ``prefix_sum=False`` (the Pallas kernels), with the equivalent
-    index bisection of ``_first_n_mask``.
+    pattern (``_kth_magnitudes``). Ties at the threshold are filled in
+    index order with a prefix sum.
     """
-    mag = jnp.abs(x.astype(jnp.float32))
-    bits = jax.lax.bitcast_convert_type(mag, jnp.int32)      # >= 0 for |x|
-    k = jnp.broadcast_to(jnp.asarray(k, jnp.int32), mag.shape[:-1] + (1,))
-
-    # invariant: count(bits >= lo) >= k, count(bits >= hi) < k
-    lo = jnp.zeros_like(k)
-    hi = jnp.max(bits, axis=-1, keepdims=True) + 1
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = lo + (hi - lo) // 2
-        enough = jnp.sum((bits >= mid).astype(jnp.int32), axis=-1,
-                         keepdims=True) >= k
-        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid)
-
-    lo, hi = jax.lax.fori_loop(0, 31, body, (lo, hi))
+    (mag,), (lo,), k = _kth_magnitudes([x], k)
     thresh = jax.lax.bitcast_convert_type(lo, jnp.float32)   # k-th largest |x|
     greater = mag > thresh
     n_greater = jnp.sum(greater.astype(jnp.int32), axis=-1, keepdims=True)
     equal = mag == thresh
-    if prefix_sum:
-        fill = _prefix_count(equal) <= (k - n_greater)
-    else:
-        fill = _first_n_mask(equal, k - n_greater)
+    fill = _prefix_count(equal) <= (k - n_greater)
     return greater | (equal & fill)
+
+
+def topk_keep(xs: list[Array], k: Array) -> list[Array]:
+    """Each block of ``xs`` with its top-k magnitudes per row kept and
+    every other entry +0.0, in float32: ``x * topk_threshold_mask(x, k)``
+    as XLA compiles it (its simplifier turns the product with a converted
+    mask into this select), and so bit for bit the jitted jnp path. This
+    is the Pallas kernel's body. The chip's Pallas lowering has no prefix
+    sum, so ties are filled in index order by the index bisection of
+    ``_first_n_mask``, and that fill runs only where it can change the
+    result: where some row has more ties than its k leaves room for, at a
+    nonzero threshold or among zeros one of which is -0.0. Elsewhere
+    every tie is kept, or the ties are zeros that come out as +0.0
+    whether kept or not."""
+    mags, los, k = _kth_magnitudes(xs, k)
+    out = []
+    for x, mag, lo in zip(xs, mags, los):
+        x = x.astype(jnp.float32)
+        thresh = jax.lax.bitcast_convert_type(lo, jnp.float32)
+        greater = mag > thresh
+        equal = mag == thresh
+        room = k - jnp.sum(greater.astype(jnp.int32), axis=-1, keepdims=True)
+        n_equal = jnp.sum(equal.astype(jnp.int32), axis=-1, keepdims=True)
+        signed = jax.lax.bitcast_convert_type(x, jnp.int32) == -2 ** 31
+        signed = jnp.sum(signed.astype(jnp.int32), axis=-1, keepdims=True)
+        crowded = (n_equal > room) & ((lo > 0) | (signed > 0))
+        crowded = jnp.max(crowded.astype(jnp.int32)) > 0
+        out.append(jax.lax.cond(
+            crowded,
+            lambda x=x, greater=greater, equal=equal, room=room: jnp.where(
+                greater | (equal & _first_n_mask(equal, room)), x, 0.0),
+            lambda x=x, greater=greater, equal=equal: jnp.where(
+                greater | equal, x, 0.0)))
+    return out
 
 
 def _pad_to_blocks(vec: Array, block: int) -> tuple[Array, int]:

@@ -1,5 +1,7 @@
 """Per-kernel validation: shape/dtype sweeps, Pallas (interpret=True) vs
 the pure-jnp ref oracles."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,8 +11,9 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.score_norm.ops import l2_norm
 from repro.kernels.score_norm.ref import l2_norm_ref
+from repro.kernels.topk_sparsify import kernel as topk_kernel
 from repro.kernels.topk_sparsify.ops import (block_topk_sparsify,
-                                             block_topk_sparsify_rows)
+                                             block_topk_sparsify_matrix)
 from repro.kernels.topk_sparsify.ref import block_topk_ref, block_topk_rows_ref
 
 
@@ -43,14 +46,15 @@ def test_topk_with_ties():
 
 
 def test_topk_rows_dynamic_k_matches_ref():
-    """Pallas rows kernel (a per-row k column per tile) and the jitted
-    bisection fast path both match the sort-based rows oracle."""
+    """Pallas matrix kernel at D = block (one block per row, a per-row k
+    column per tile) and the jitted bisection fast path both match the
+    sort-based rows oracle."""
     from repro.fl.compression import _rows_topk_bisect
     rows = jax.random.normal(jax.random.PRNGKey(3), (12, 1024))
     ks = jnp.asarray([1, 7, 64, 100, 512, 1000, 1024, 3, 333, 900, 2, 50],
                      jnp.int32)
     want = block_topk_rows_ref(rows, ks)
-    got_pallas = block_topk_sparsify_rows(rows, ks)
+    got_pallas = block_topk_sparsify_matrix(rows, ks, block=1024)
     got_bisect = jax.jit(_rows_topk_bisect)(rows, ks)
     np.testing.assert_array_equal(np.asarray(got_pallas), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(got_bisect), np.asarray(want))
@@ -66,14 +70,115 @@ def test_topk_rows_extreme_dynamic_range():
     rows = jnp.asarray(row)[None, :]
     ks = jnp.asarray([11], jnp.int32)
     want = block_topk_rows_ref(rows, ks)
-    np.testing.assert_array_equal(np.asarray(block_topk_sparsify_rows(rows, ks)),
-                                  np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(block_topk_sparsify_matrix(rows, ks, block=4096)),
+        np.asarray(want))
     from repro.fl.compression import _rows_topk_bisect
     np.testing.assert_array_equal(np.asarray(jax.jit(_rows_topk_bisect)(rows, ks)),
                                   np.asarray(want))
     # and the oracle itself keeps exactly the outlier + the ten 2.0s
     kept = np.nonzero(np.asarray(want)[0])[0]
     np.testing.assert_array_equal(kept, np.arange(4085, 4096))
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _block_view_ref(mat, ks, block):
+    """The [N, D] top-k as the jnp path spells it: zero-pad D to whole
+    blocks, view as [N * nb, block] rows, sort-based rows oracle, back.
+    Jitted, so a dropped entry is +0.0, as in the jitted jnp path."""
+    n, d = mat.shape
+    nb = -(-d // block)
+    rows = jnp.pad(mat, ((0, 0), (0, nb * block - d))).reshape(n * nb, block)
+    out = block_topk_rows_ref(rows, jnp.repeat(ks, nb))
+    return out.reshape(n, nb * block)[:, :d]
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.int32)
+
+
+def _matrix_case(name, n, d, block, rng):
+    mat = rng.normal(size=(n, d)).astype(np.float32)
+    ks = rng.integers(1, block + 1, size=n).astype(np.int32)
+    ks[0], ks[-1] = 1, block
+    if name == "ties":
+        # exact zeros and a repeated value, so thresholds fall on ties at
+        # zero and above it, more of them than k leaves room for
+        mat[:, ::5] = 0.0
+        mat[:, 1::3] = np.float32(0.75)
+        mat[:, 2::7] = -0.0
+        ks[1:] = np.linspace(1, block, n - 1).astype(np.int32)
+    elif name == "zeros":
+        # more zeros than the k leave room for, some of them -0.0, whose
+        # sign survives only where the index fill keeps it
+        mat[:, ::2] = 0.0
+        mat[:, 1::6] = -0.0
+        ks[1:] = block - np.arange(1, n) * 3
+    elif name == "range":
+        mat[0, :] = 1.0
+        mat[0, -1] = 1e30
+        mat[0, -11:-1] = 2.0
+        ks[0] = 11
+    elif name == "nan":
+        mat[n // 2, 3] = np.nan
+        mat[n // 2, d - 1] = np.nan
+    return jnp.asarray(mat), jnp.asarray(ks)
+
+
+# D ragged against the block and against 128 lanes; N below, at and off a
+# row tile; ties, zeros of both signs, a 1e30 outlier beside ones and
+# twos, and NaN; "row-tiles" forces 8-row tiles, so the last row tile is
+# ragged too
+@pytest.mark.parametrize("name,n,d,block", [
+    ("random", 1, 1000, 256),
+    ("random", 5, 3001, 1024),
+    ("random", 13, 2100, 512),
+    ("ties", 5, 3001, 1024),
+    ("ties", 13, 1300, 256),
+    ("zeros", 5, 3001, 1024),
+    ("range", 1, 4096 + 77, 4096),
+    ("nan", 5, 3001, 1024),
+    ("row-tiles", 13, 1100, 256),   # a shape of its own: jit caches by shape
+])
+def test_topk_matrix_matches_block_view(name, n, d, block, monkeypatch):
+    """The matrix kernel over [N, D]'s own tiles equals the block view's
+    top-k bit for bit; with NaN in a row, it equals the jnp path."""
+    from repro.fl.compression import batch_block_topk
+    if name == "row-tiles":
+        monkeypatch.setattr(topk_kernel, "_TILE_BYTES", 8 * block * 4)
+        assert topk_kernel.matrix_tile(n, block, jnp.float32) == (8, 1)
+    mat, ks = _matrix_case(name, n, d, block, np.random.default_rng(n + d))
+    got = block_topk_sparsify_matrix(mat, ks, block=block)
+    if name == "nan":
+        want = jax.jit(lambda m, g: batch_block_topk(
+            m, g, block=block, use_pallas=False, skip_full=False))(
+                mat, ks.astype(jnp.float32) / block)
+        # NaN is neither above nor at any threshold, so neither path
+        # keeps it
+        assert np.isfinite(np.asarray(got)).all()
+    else:
+        want = _block_view_ref(mat, ks, block)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("gamma", ["mixed", "full"])
+def test_batch_block_topk_pallas_matches_jnp(gamma):
+    """batch_block_topk gives the same bits through the kernel and the jnp
+    path at a ragged shape, with the full-precision skip taken or not,
+    and under vmap (the seed sweep)."""
+    from repro.fl.compression import batch_block_topk
+    rng = np.random.default_rng(11)
+    mat = jnp.asarray(rng.normal(size=(2, 7, 2500)).astype(np.float32))
+    g = (jnp.asarray([[0.05, 0.1, 0.3, 1.0, 0.5, 0.77, 1.0]] * 2, jnp.float32)
+         if gamma == "mixed" else jnp.ones((2, 7), jnp.float32))
+    for skip_full in (True, False):
+        outs = [jax.jit(lambda m, g, p=p: batch_block_topk(
+                    m, g, block=1024, use_pallas=p, skip_full=skip_full))(
+                    mat[0], g[0]) for p in (True, False)]
+        np.testing.assert_array_equal(_bits(outs[0]), _bits(outs[1]))
+    swept = [jax.jit(jax.vmap(lambda m, g, p=p: batch_block_topk(
+                m, g, block=1024, use_pallas=p)))(mat, g) for p in (True, False)]
+    np.testing.assert_array_equal(_bits(swept[0]), _bits(swept[1]))
 
 
 def test_topk_rows_matches_per_vector_static():

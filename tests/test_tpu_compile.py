@@ -24,8 +24,7 @@ from repro.kernels.dual_solve.kernel import (N_SCALARS, dual_solve_pallas,
                                              dual_solve_pallas_joint)
 from repro.kernels.dual_solve.ref import joint_levels
 from repro.kernels.score_norm.kernel import sq_sum_partials
-from repro.kernels.topk_sparsify.kernel import (topk_sparsify_pallas,
-                                                topk_sparsify_rows_pallas)
+from repro.kernels.topk_sparsify.kernel import topk_sparsify_matrix_pallas
 from repro.models import cnn
 
 N_SOLVER = 1024
@@ -86,29 +85,43 @@ def test_dual_solve_compiles_for_v5e(one_chip, joint, scaled):
     _compile(lambda *a: fn(*a, interpret=False, **kw), *args)
 
 
-def test_topk_rows_compiles_for_v5e(one_chip, cnn_width):
-    n_rows = N_CLIENTS * -(-cnn_width // BLOCK)
-    rows = jax.ShapeDtypeStruct((n_rows, BLOCK), jnp.float32,
-                                sharding=one_chip)
-    ks = jax.ShapeDtypeStruct((n_rows,), jnp.int32, sharding=one_chip)
+@pytest.mark.parametrize("n", [N_CLIENTS, 200])
+def test_topk_matrix_compiles_for_v5e(one_chip, cnn_width, n):
+    """The [N, D] top-k kernel over the CNN's flat updates, within the
+    default VMEM: whole-client tiles of two blocks at N = 50, 112-row
+    tiles of one block at N = 200."""
+    mat = jax.ShapeDtypeStruct((n, cnn_width), jnp.float32, sharding=one_chip)
+    ks = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    _compile(lambda m, k: topk_sparsify_matrix_pallas(m, k, block=BLOCK,
+                                                      interpret=False),
+             mat, ks)
+
+
+def test_batch_block_topk_tpu_path_has_no_block_view(one_chip, cnn_width,
+                                                      monkeypatch):
+    """``batch_block_topk``'s kernel path at N = 50 compiles to the kernel
+    over [N, D] itself: no [N * nb, 4096] view or other [N, D]-sized
+    buffer among its temporaries."""
+    from repro.fl.compression import batch_block_topk
+    from repro.kernels.topk_sparsify import ops
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    mat = jax.ShapeDtypeStruct((N_CLIENTS, cnn_width), jnp.float32,
+                               sharding=one_chip)
+    gamma = jax.ShapeDtypeStruct((N_CLIENTS,), jnp.float32, sharding=one_chip)
     compiled = _compile(
-        lambda r, k: topk_sparsify_rows_pallas(r, k, interpret=False),
-        rows, ks)
-    # the [R, 4096] buffer, its output and the kernel's temporaries fit
-    # in the 16 GB of one v5e chip
-    mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes)
-    assert n_rows * BLOCK * 4 * 2 <= used < 16e9
+        lambda m, g: batch_block_topk(m, g, block=BLOCK, use_pallas=True),
+        mat, gamma)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        N_CLIENTS * cnn_width * 4)
 
 
 def test_topk_static_compiles_for_v5e(one_chip, cnn_width):
-    """One client's flat update at the static k of gamma = 0.1; its block
-    count is not a multiple of 8, so the kernel's zero-row pad is in."""
-    n = -(-cnn_width // BLOCK) * BLOCK
-    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
-    _compile(lambda v: topk_sparsify_pallas(v, k=410, block=BLOCK,
-                                            interpret=False), vec)
+    """One client's flat update at the static k of gamma = 0.1, as
+    ``ops.block_topk_sparsify`` runs it: the kernel over one row, whose
+    block count is not a whole number of tiles."""
+    vec = jax.ShapeDtypeStruct((1, cnn_width), jnp.float32, sharding=one_chip)
+    _compile(lambda v: topk_sparsify_matrix_pallas(
+        v, jnp.full((1,), 410, jnp.int32), block=BLOCK, interpret=False), vec)
 
 
 def test_sq_sum_partials_compiles_for_v5e(one_chip, cnn_width):

@@ -101,8 +101,9 @@ def test_topk_prefix_sum_carries_the_callers_scope():
     reduce-window that ``jnp.cumsum`` lowers to, it is lowered in place,
     so the caller's scope names it in the program (``cumsum`` lowers it
     as an outlined function whose ops carry no name stack); the counts
-    and the mask are the same."""
-    from repro.kernels.topk_sparsify.ref import (_prefix_count,
+    are the same, and so are the values kept, against the kernel body's
+    index fill."""
+    from repro.kernels.topk_sparsify.ref import (_prefix_count, topk_keep,
                                                  topk_threshold_mask)
     rng = np.random.default_rng(1)
     x = jnp.asarray(np.round(rng.normal(size=(6, 256)), 1)
@@ -111,8 +112,11 @@ def test_topk_prefix_sum_carries_the_callers_scope():
     flags = jnp.abs(x) == 0.5
     np.testing.assert_array_equal(
         _prefix_count(flags), jnp.cumsum(flags.astype(jnp.int32), axis=-1))
-    np.testing.assert_array_equal(topk_threshold_mask(x, k),
-                                  topk_threshold_mask(x, k, prefix_sum=False))
+    kept = jax.jit(lambda x, k: x * topk_threshold_mask(x, k))(x, k)
+    np.testing.assert_array_equal(
+        np.asarray(kept).view(np.int32),
+        np.asarray(jax.jit(lambda x, k: topk_keep([x], k)[0])(x, k))
+        .view(np.int32))
 
     def sparsify(x, k):
         with jax.named_scope("fl.sparsify"):
