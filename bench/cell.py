@@ -2,8 +2,9 @@
 and a traffic mix, and driven chunk by chunk as users run it.
 
 Everything here goes through the program's public entry points
-(``repro.fl.FederatedTrainer`` and its configs); the data and the
-weights are the benchmark's own (``data.py``, ``reference.init_params``).
+(``repro.fl.FederatedTrainer`` and its configs); the data, the weights
+and the model's inputs to the trainer come from the configuration's
+model family (``family.py``).
 """
 from __future__ import annotations
 
@@ -12,10 +13,10 @@ import math
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import data as bench_data
+import family
 import reference
 
 BENCH = Path(__file__).resolve().parent
@@ -36,8 +37,22 @@ def spec(workload: str, benchmark: dict):
     cell = cells[workload]
     configs = {c["name"]: c for c in benchmark["configs"]}
     config = json.loads((ROOT / configs[cell["config"]]["file"]).read_text())
+    family.directory(config["model"]["family"])
     return cell, config, load("traffic", cell["traffic"]), \
         load("limits", workload)
+
+
+def inputs(config: dict, seed: int):
+    """The data and the initial weights of one seed."""
+    return (bench_data.make(config, seed),
+            reference.init_params(config["model"], seed))
+
+
+def trainer_inputs(config: dict, traffic: dict, data: dict, params0) -> dict:
+    """The trainer's model arguments, from the configuration's family:
+    ``model_loss``, ``model_params``, ``client_datasets``, ``eval_fn``."""
+    return family.load(config["model"]["family"], "program").trainer_inputs(
+        config, traffic, data, params0)
 
 
 class Cell:
@@ -45,21 +60,11 @@ class Cell:
 
     def __init__(self, config: dict, traffic: dict, seed: int, mesh=None):
         from repro.configs import ChannelConfig, FairEnergyConfig, FLConfig
-        from repro.configs.base import ModelConfig
         from repro.fl import FederatedTrainer
-        from repro.models import cnn
 
         self.config, self.traffic = config, traffic
         self.chunk = traffic["chunk_rounds"]
-        self.data = bench_data.make(config, seed)
-        self.params0 = reference.init_params(config["model"], seed)
-        m = config["model"]
-        mcfg = ModelConfig(name=config["name"], family="cnn",
-                           n_layers=len(m["cnn_channels"]), d_model=0,
-                           cnn_channels=tuple(m["cnn_channels"]),
-                           cnn_dense=m["cnn_dense"],
-                           input_hw=tuple(m["input_hw"]),
-                           n_classes=m["n_classes"], dtype=m["dtype"])
+        self.data, self.params0 = inputs(config, seed)
         ch_cfg = ChannelConfig(n_clients=config["n_clients"],
                                **config["channel"])
         fe_cfg = FairEnergyConfig(
@@ -68,22 +73,9 @@ class Cell:
         fl_cfg = FLConfig(rounds=self.chunk, local_steps=traffic["local_steps"],
                           local_batch=traffic["local_batch"], lr=traffic["lr"],
                           dirichlet_beta=config["data"]["dirichlet_beta"])
-        d = self.data
-        clients = [dict(images=d["images"][p], labels=d["labels"][p])
-                   for p in d["parts"]]
-        test_x = jnp.asarray(d["test_images"])
-        test_y = jnp.asarray(d["test_labels"])
-
-        @jax.jit
-        def eval_fn(p):
-            logits = cnn.cnn_forward(p, test_x, mcfg)
-            return jnp.mean((jnp.argmax(logits, -1) == test_y)
-                            .astype(jnp.float32))
-
         self.trainer = FederatedTrainer(
-            model_loss=lambda p, b: cnn.cnn_loss(p, b, mcfg),
-            model_params=self.params0, client_datasets=clients,
-            eval_fn=eval_fn, fl_cfg=fl_cfg, fe_cfg=fe_cfg, ch_cfg=ch_cfg,
+            **trainer_inputs(config, traffic, self.data, self.params0),
+            fl_cfg=fl_cfg, fe_cfg=fe_cfg, ch_cfg=ch_cfg,
             controller=traffic["controller"],
             **{k: traffic[k] for k in ("fixed_k", "eco_gamma") if k in traffic},
             seed=config["fleet_seed"], mesh=mesh)

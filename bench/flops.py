@@ -1,44 +1,23 @@
 """Model FLOPs of a round, from the configuration's shapes.
 
-A multiply-add counts two FLOPs. Training one sample costs the forward
-pass, the weight gradients (as many multiply-adds as the forward) and
-the input gradients of every layer but the first. Pooling, activations
-and the round's non-model stages (decide, sparsify, aggregate) are not
-counted: they do no matrix work.
+The model's own counts come from its family (``bench/families/<family>/
+flops.py``): the FLOPs of training one sample and of one evaluation. The
+round's non-model stages (decide, sparsify, aggregate) are not counted:
+they do no matrix work.
 """
 from __future__ import annotations
 
-
-def layer_macs(model: dict) -> list:
-    """Multiply-adds per sample of each matrix layer, input to output."""
-    h, w, c = model["input_hw"]
-    macs = []
-    for c_out in model["cnn_channels"]:
-        macs.append(h * w * 9 * c * c_out)        # 3x3 SAME conv
-        h, w, c = h // 2, w // 2, c_out           # 2x2 max pool
-    flat = h * w * c
-    macs.append(flat * model["cnn_dense"])
-    macs.append(model["cnn_dense"] * model["n_classes"])
-    return macs
-
-
-def forward_flops(model: dict) -> int:
-    return 2 * sum(layer_macs(model))
-
-
-def train_flops(model: dict) -> int:
-    macs = layer_macs(model)
-    return 2 * (2 * sum(macs) + sum(macs[1:]))
+import family
 
 
 def round_flops(config: dict, traffic: dict) -> float:
     """FLOPs per round: the training of every client whose update the
     round consumes (all N where the controller scores every update, the
-    K selected where it picks blind), and the chunk's one evaluation of
-    the test set spread over its rounds."""
-    model = config["model"]
+    K selected where it picks blind), and the chunk's one evaluation
+    spread over its rounds."""
+    fam = family.load(config["model"]["family"], "flops")
     clients = (config["n_clients"] if traffic["consumed_updates"] == "all"
                else traffic["fixed_k"])
     samples = clients * traffic["local_steps"] * traffic["local_batch"]
-    evals = config["data"]["n_test"] / traffic["chunk_rounds"]
-    return samples * train_flops(model) + evals * forward_flops(model)
+    return (samples * fam.train_flops(config["model"])
+            + fam.eval_flops(config) / traffic["chunk_rounds"])

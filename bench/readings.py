@@ -12,7 +12,8 @@ under test for the trainers built while it is active:
 
 * ``state_unchanged``: the round's apply adds nothing to the parameters;
 * ``half_batch``: the client loss is the mean over the first half of
-  each minibatch;
+  each minibatch (every leaf of the batch the family's ``model_loss``
+  is handed is cut to its first half);
 * ``decision_altered``: the controller's bandwidths come out 10 % low;
 * ``update_altered``: the round's aggregated update is scaled by 1.5
   where the round produces it, before it is applied.
@@ -37,7 +38,8 @@ def planted(fault: str | None):
     import jax.numpy as jnp
     from repro.core.controllers import baselines, fairenergy
     from repro.fl import server
-    from repro.models import cnn
+
+    import cell as cell_mod
 
     saved = []
 
@@ -50,9 +52,14 @@ def planted(fault: str | None):
         patch(server, "unflatten_update", lambda vec, spec: jax.tree_util.tree_map(
             jnp.zeros_like, orig(vec, spec)))
     elif fault == "half_batch":
-        orig = cnn.cnn_loss
-        patch(cnn, "cnn_loss", lambda p, b, cfg: orig(
-            p, {k: v[: v.shape[0] // 2] for k, v in b.items()}, cfg))
+        orig = cell_mod.trainer_inputs
+
+        def trainer_inputs(*args):
+            ins = orig(*args)
+            loss = ins["model_loss"]
+            return dict(ins, model_loss=lambda p, b: loss(
+                p, jax.tree_util.tree_map(lambda v: v[: v.shape[0] // 2], b)))
+        patch(cell_mod, "trainer_inputs", trainer_inputs)
     elif fault == "decision_altered":
         for cls in (fairenergy.FairEnergy, baselines.EcoRandom,
                     baselines.ScoreMax):
@@ -101,12 +108,10 @@ def control_reading(workload, bench, seed):
     import jax.numpy as jnp
 
     import cell as cell_mod
-    import data as bench_data
     import reference
     _, config, traffic, _ = cell_mod.spec(workload, bench)
     c = traffic["chunk_rounds"]
-    data = bench_data.make(config, seed)
-    params0 = reference.init_params(config["model"], seed)
+    data, params0 = cell_mod.inputs(config, seed)
     low = reference.follow(config, traffic, data, params0, c,
                            dtype=jnp.bfloat16,
                            precision=jax.lax.Precision.DEFAULT)

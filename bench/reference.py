@@ -5,9 +5,11 @@ Independent of the program: nothing here imports ``repro``. It restates
 the round's semantics in straightforward code, from the benchmark's own
 weights and data and the seed:
 
-* the model: the paper's CNN (conv3x3 -> relu -> maxpool2, twice, then
-  dense -> relu -> dense) in float32 at the matmul precision the
-  configuration states (``precision.matmul``);
+* the model: the reference half of the configuration's model family
+  (``bench/families/<family>/reference.py``, which imports nothing of
+  the program either), in float32 at the matmul precision the
+  configuration states (``precision.matmul``): its weights, its local
+  step and its evaluation;
 * the seeded draws the round is defined by: client powers and distances
   (numpy ``default_rng(seed)``), Rayleigh fading ``Exp(fold_in(key,
   round))``, minibatch indices ``floor(U * len)`` from
@@ -24,7 +26,7 @@ weights and data and the seed:
   the lower index, by a stable sort;
 * aggregate and apply: the |D_i|-weighted mean of the selected sparse
   updates, added to the parameters;
-* eval: accuracy over the test set.
+* eval: accuracy over the held-out set.
 
 ``follow`` replays the rounds of the program's warm-up chunk with the
 program's own selection and compression ratios (its decisions are the
@@ -41,12 +43,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import family
+
 LN2 = math.log(2.0)
 BLOCK = 4096
 # the program's PRNG stream tags, folded into PRNGKey(seed)
 CTRL_STREAM = 1 << 20
 SAMPLE_STREAM = 2 << 20
-INIT_STREAM = 0x62656E  # the benchmark's own weight stream
 
 
 def stated_precision(config: dict):
@@ -56,112 +59,26 @@ def stated_precision(config: dict):
 
 # ---------------------------------------------------------------- model ----
 def init_params(model: dict, seed: int):
-    """The CNN's weights from the seed, in one jitted call on the device:
-    conv weights N(0, 1/fan_in), dense weights truncated-normal on
-    [-2, 2] over sqrt(fan_in), zero biases. Float32 master copies."""
-    h, w, c_in = model["input_hw"]
-    chans, dense, n_out = model["cnn_channels"], model["cnn_dense"], \
-        model["n_classes"]
-
-    @jax.jit
-    def make(key):
-        keys = jax.random.split(key, len(chans) + 2)
-        p, c_prev, hh, ww = {}, c_in, h, w
-        for i, c in enumerate(chans):
-            p[f"conv{i}"] = {
-                "w": jax.random.normal(keys[i], (3, 3, c_prev, c))
-                / jnp.sqrt(9.0 * c_prev),
-                "b": jnp.zeros((c,), jnp.float32)}
-            c_prev, hh, ww = c, hh // 2, ww // 2
-        flat = hh * ww * c_prev
-        for name, k, d_in, d_out in (("fc1", keys[-2], flat, dense),
-                                     ("fc2", keys[-1], dense, n_out)):
-            p[name] = {"w": jax.random.truncated_normal(
-                k, -2.0, 2.0, (d_in, d_out)) / math.sqrt(d_in),
-                "b": jnp.zeros((d_out,), jnp.float32)}
-        return p
-
-    return make(jax.random.fold_in(jax.random.PRNGKey(seed), INIT_STREAM))
-
-
-def forward(p, x, precision):
-    i = 0
-    while f"conv{i}" in p:
-        y = jax.lax.conv_general_dilated(
-            x, p[f"conv{i}"]["w"].astype(x.dtype), (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision)
-        y = jax.nn.relu(y + p[f"conv{i}"]["b"].astype(x.dtype))
-        x = jax.lax.reduce_window(y, -jnp.inf, jax.lax.max,
-                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-        i += 1
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(jnp.dot(x, p["fc1"]["w"].astype(x.dtype),
-                            precision=precision) + p["fc1"]["b"].astype(x.dtype))
-    return (jnp.dot(x, p["fc2"]["w"].astype(x.dtype), precision=precision)
-            + p["fc2"]["b"].astype(x.dtype))
-
-
-def loss(p, images, labels, precision):
-    logits = forward(p, images, precision).astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
+    """The model's initial weights from the seed, by its family's
+    ``init_params``."""
+    return family.load(model["family"], "reference").init_params(model, seed)
 
 
 def leaves(p):
-    """The parameter leaves in the order the update is flattened in."""
-    return [p[k][j] for k in sorted(p) for j in sorted(p[k])]
-
-
-def leaf_names(p):
-    return [f"{k}/{j}" for k in sorted(p) for j in sorted(p[k])]
-
-
-def flatten(p):
-    return jnp.concatenate([v.astype(jnp.float32).reshape(-1)
-                            for v in leaves(p)])
+    """The parameter leaves in the order the update is flattened in:
+    sorted keys at every level of nesting, the order JAX flattens a dict
+    in."""
+    return jax.tree_util.tree_leaves(p)
 
 
 def unflatten(vec, like):
-    out, off = {}, 0
-    for k in sorted(like):
-        out[k] = {}
-        for j in sorted(like[k]):
-            n = like[k][j].size
-            out[k][j] = vec[off:off + n].reshape(like[k][j].shape)
-            off += n
-    return out
-
-
-def make_client_step(lr: float, dtype, precision):
-    """vmapped local SGD: (params, images [N,S,B,...], labels [N,S,B]) ->
-    (flat updates [N, D] float32, last-step losses [N])."""
-    def one(p0, images, labels):
-        p = p0
-        for s in range(images.shape[0]):
-            ls, g = jax.value_and_grad(loss)(p, images[s].astype(dtype),
-                                             labels[s], precision)
-            p = jax.tree_util.tree_map(lambda a, b: a - jnp.asarray(lr, dtype)
-                                       * b.astype(dtype), p, g)
-        d = jax.tree_util.tree_map(lambda a, b: a - b, p, p0)
-        return flatten(d), ls
-
-    return jax.jit(jax.vmap(one, in_axes=(None, 0, 0)))
-
-
-def make_eval(dtype, precision, max_block=2500):
-    @jax.jit
-    def acc(p, images, labels):
-        block = max(b for b in range(1, min(max_block, images.shape[0]) + 1)
-                    if images.shape[0] % b == 0)
-        def one(args):
-            im, lb = args
-            pred = jnp.argmax(forward(p, im.astype(dtype), precision), -1)
-            return jnp.sum((pred == lb).astype(jnp.int32))
-        n = images.shape[0] // block
-        hits = jax.lax.map(one, (images.reshape((n, block) + images.shape[1:]),
-                                 labels.reshape(n, block)))
-        return jnp.sum(hits) / images.shape[0]
-    return acc
+    """A flat vector in the nesting and shapes of ``like``."""
+    flat, tree = jax.tree_util.tree_flatten(like)
+    out, off = [], 0
+    for v in flat:
+        out.append(vec[off:off + v.size].reshape(v.shape))
+        off += v.size
+    return jax.tree_util.tree_unflatten(tree, out)
 
 
 # -------------------------------------------------------------- draws ----
@@ -370,12 +287,9 @@ def follow(config: dict, traffic: dict, data: dict, params0, rounds: int,
     d = sum(int(np.prod(v.shape)) for v in leaves(params0))
     s_bits, i_bits = 32.0 * d, float(d)
     power, pathloss = network(seed, n, ch)
-    images = jnp.asarray(data["images"])
-    labels = jnp.asarray(data["labels"])
-    test_x = jnp.asarray(data["test_images"])
-    test_y = jnp.asarray(data["test_labels"])
-    client_step = make_client_step(traffic["lr"], dtype, precision)
-    accuracy = make_eval(dtype, precision)
+    model = family.load(config["model"]["family"], "reference")
+    client_step = model.client_step(traffic["lr"], dtype, precision)
+    accuracy = model.accuracy(data, dtype, precision)
     params = jax.tree_util.tree_map(lambda v: jnp.asarray(v, dtype), params0)
     offsets = np.concatenate([[0], np.cumsum(lengths)[:-1]])
     flat_index = np.concatenate(parts)
@@ -386,7 +300,7 @@ def follow(config: dict, traffic: dict, data: dict, params0, rounds: int,
         h = gains(seed, pathloss, r, ch["rayleigh"])
         local = batch_indices(seed, r, lengths, steps, batch)
         rows = flat_index[offsets[:, None, None] + local]
-        upd, losses = client_step(params, images[rows], labels[rows])
+        upd, losses = client_step(params, model.minibatch(data, rows))
         norms = np.asarray(jnp.sqrt(jnp.sum(jnp.square(
             upd.astype(jnp.float32)), axis=1)), np.float64)
         if fair:
@@ -419,11 +333,11 @@ def follow(config: dict, traffic: dict, data: dict, params0, rounds: int,
         out["loss"].append(float(jnp.mean(losses.astype(jnp.float32))))
         evaluated = (not math.isnan(logs[r]["accuracy"]) if logs is not None
                      else r in (0, rounds - 1))
-        out["acc"].append(float(accuracy(params, test_x, test_y))
+        out["acc"].append(float(accuracy(params))
                           if evaluated else float("nan"))
         out["own"].append(own)
-    out["params"] = {k: {j: np.asarray(v, np.float32) for j, v in g.items()}
-                     for k, g in params.items()}
+    out["params"] = jax.tree_util.tree_map(
+        lambda v: np.asarray(v, np.float32), params)
     return out
 
 

@@ -49,7 +49,6 @@ import trace as base  # noqa: E402
 
 STAGES = ("sample", "client_step", "decide", "sparsify", "aggregate", "eval")
 SPANS = ("fl.dispatch", "fl.sync", "fl.logs")
-STAGE = re.compile(r"(?<![\w.])fl\.([a-z_]+)")
 OPCODE = re.compile(r"(?<![\w.%-])([a-z][\w-]*)\(")
 REF = re.compile(r"%([^\s,(){}]+)")
 FUSED = re.compile(r"calls=%([^\s,)]+)")
@@ -58,12 +57,9 @@ CONTROL = re.compile(r"(?:body|condition)=%([^\s,)]+)"
 CONTROL_OPS = ("while", "conditional", "call")
 
 
-def stage(op_name: str):
-    """The round's stage of one device op: the innermost ``fl.<stage>``
-    scope of its name stack (``sparsify`` for ``.../fl.sparsify/cond/
-    ...``), or None outside every such scope."""
-    found = STAGE.findall(op_name or "")
-    return found[-1] if found else None
+# the round's stage of one device op: the innermost ``fl.<stage>`` scope
+# of its name stack, or None outside every such scope
+stage = base.scope
 
 
 def _instructions(hlo_text: str) -> list:
@@ -197,11 +193,10 @@ def summarize(events, host, window=None, top=10) -> dict:
     inside = [e for e in events
               if e["start"] < w1 and e["start"] + e["dur"] > w0]
     nd = out["devices"]
-    stage_s, named_s, ops, inferred = {}, {}, {}, {}
+    stage_s, ops, inferred = {}, {}, {}
     for e, d in zip(inside, base.exclusive_times(inside, w0, w1)):
         st, own = e.get("stage"), stage(e["op_name"])
         stage_s[st or "untagged"] = stage_s.get(st or "untagged", 0.0) + d
-        named_s[own or "untagged"] = named_s.get(own or "untagged", 0.0) + d
         if st and not own:
             key = f"{st}:{e['name']}"
             inferred[key] = inferred.get(key, 0.0) + d
@@ -238,7 +233,7 @@ def summarize(events, host, window=None, top=10) -> dict:
 
     out.update(
         stage_s={k: v / nd * ns for k, v in stage_s.items()},
-        named_s={k: v / nd * ns for k, v in named_s.items()},
+        named_s=out["scope_s"],
         device_ops=top_of(ops), inferred_ops=top_of(inferred),
         idle_gaps=[[gap_name(a, b), g * ns] for g, a, b in
                    sorted(gaps, reverse=True)[:top]])

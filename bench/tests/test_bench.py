@@ -91,21 +91,23 @@ def test_recorded_trace_names_match_classify():
 
 # ------------------------------------------------------------ flops ----
 def test_cnn_flops_by_hand():
+    import family
+    cnn = family.load("cnn", "flops")
     model = cell_mod.load("configs", "fmnist-cnn.n50")["model"]
     conv0 = 28 * 28 * 32 * (3 * 3 * 1)
     conv1 = 14 * 14 * 64 * (3 * 3 * 32)
     fc1, fc2 = 7 * 7 * 64 * 512, 512 * 10
     fwd = 2 * (conv0 + conv1 + fc1 + fc2)
-    assert flops.forward_flops(model) == fwd == 10_898_432
+    assert cnn.forward_flops(model) == fwd == 10_898_432
     # forward + weight grads + input grads of all layers but the first
-    assert flops.train_flops(model) == fwd + fwd + 2 * (conv1 + fc1 + fc2)
+    assert cnn.train_flops(model) == fwd + fwd + 2 * (conv1 + fc1 + fc2)
     config = cell_mod.load("configs", "fmnist-cnn.n50")
     traffic = cell_mod.load("traffic", "fairenergy")
     assert flops.round_flops(config, traffic) == pytest.approx(
-        50 * 2 * 64 * flops.train_flops(model) + 10_000 * fwd / 10)
+        50 * 2 * 64 * cnn.train_flops(model) + 10_000 * fwd / 10)
     eco = cell_mod.load("traffic", "ecorandom")
     assert flops.round_flops(config, eco) == pytest.approx(
-        16 * 2 * 64 * flops.train_flops(model) + 10_000 * fwd / 10)
+        16 * 2 * 64 * cnn.train_flops(model) + 10_000 * fwd / 10)
 
 
 def test_param_count_matches_config():

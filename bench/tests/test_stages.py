@@ -220,6 +220,59 @@ def test_recorded_chunk_has_every_stage(workload):
         assert e["layer"] == trace_mod.classify(e["op_name"], e["name"])
 
 
+# ---------------------------------------------------------- scope_s ----
+# what the recordings read before ``trace.summarize`` gave ``scope_s``
+# (seconds): ``stages.summarize``'s ``named_s`` and the per-layer times
+PARENT_NAMED = {
+    "cnn-n50.fairenergy": {
+        "aggregate": 0.0018567680000000002, "client_step": 0.081695215,
+        "decide": 0.000794607, "eval": 0.004939095, "sample": 0.01083626,
+        "sparsify": 0.06634166500000001, "untagged": 0.028534943},
+    "cnn-n50.ecorandom": {
+        "aggregate": 0.0018563000000000002, "client_step": 0.08071409,
+        "decide": 8.79e-06, "eval": 0.004937913, "sample": 0.010837661,
+        "sparsify": 0.06809657100000001, "untagged": 0.028546526000000003},
+    "cnn-n50.scoremax": {
+        "aggregate": 0.002475291, "client_step": 0.11881206000000001,
+        "decide": 1.2076000000000001e-05, "eval": 0.004939023000000001,
+        "sample": 0.014466245, "sparsify": 0.028034343000000003,
+        "untagged": 0.026259547},
+}
+PARENT_LAYERS = {
+    "cnn-n50.fairenergy": {
+        "client_step": 0.08021589200000001, "decide": 0.000794607,
+        "eval": 0.004936542, "rest": 0.109051512},
+    "cnn-n50.ecorandom": {
+        "client_step": 0.080201719, "eval": 0.004935223000000001,
+        "rest": 0.109860909},
+    "cnn-n50.scoremax": {
+        "client_step": 0.116669118, "eval": 0.004935065000000001,
+        "rest": 0.07339440200000001},
+    "trace_events": {
+        "client_step": 0.072911849, "decide": 0.0011594980000000001,
+        "eval": 0.004936307, "rest": 0.113626456},
+}
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_scope_s_is_named_s_stage_by_stage(workload):
+    """The device time per scope that ``trace.summarize`` hands the
+    metric readers is ``bench/stages.py``'s reading by each op's own name
+    stack, and the per-layer times do not move."""
+    ev, host = _load(f"stages.{workload}.json.gz")
+    s = trace_mod.summarize(ev, host)
+    assert s["scope_s"] == stages.summarize(ev, host)["named_s"]
+    assert s["scope_s"] == PARENT_NAMED[workload]
+    assert s["layer_s"] == PARENT_LAYERS[workload]
+
+
+def test_layer_s_of_the_trace_before_the_scopes_is_the_parents():
+    ev, host = _load("trace_events.json.gz")
+    s = trace_mod.summarize(ev, host)
+    assert s["layer_s"] == PARENT_LAYERS["trace_events"]
+    assert s["scope_s"] == {"untagged": pytest.approx(s["busy_s"])}
+
+
 def test_traced_run_records_the_program_spans(monkeypatch):
     """A traced window at a size a CPU test holds: no TPU events, but
     the benchmark's and the program's host spans, in order."""

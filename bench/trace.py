@@ -5,7 +5,8 @@ and per-layer device time.
 everything after it works on plain event lists, so it can be checked on
 a small recorded trace (``bench/testdata``). Each device op is given the
 layer of the round it belongs to by the JAX name stack of its HLO
-``op_name`` (``classify``).
+``op_name`` (``classify``), and the scope the program named it under:
+the innermost ``fl.<scope>`` of that name stack (``scope``).
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
                        "collective-permute", "all-to-all")
+SCOPE = re.compile(r"(?<![\w.])fl\.([a-z_]+)")
 
 
 def classify(op_name: str, hlo_op: str = "") -> str:
@@ -30,6 +32,14 @@ def classify(op_name: str, hlo_op: str = "") -> str:
     if "jvp(" in op_name or "transpose(" in op_name:
         return "client_step"
     return "rest"
+
+
+def scope(op_name: str):
+    """The innermost ``fl.<scope>`` of an op's name stack (``sparsify``
+    for ``.../fl.sparsify/cond/...``), or None outside every such
+    scope."""
+    found = SCOPE.findall(op_name or "")
+    return found[-1] if found else None
 
 
 INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+)\s*=(.*)$")
@@ -157,9 +167,11 @@ def merge(intervals):
 
 
 def summarize(events, host, window=None, top=10):
-    """Busy and idle time, per-layer device time and the breakdown, over
-    the window (the ``bench.window`` host span, else the span of the
-    device events). Device times are averaged over the devices seen.
+    """Busy and idle time, per-layer device time (``layer_s``), device
+    time per scope (``scope_s``: by each op's own name stack, ``untagged``
+    for ops under no ``fl.*`` scope) and the breakdown, over the window
+    (the ``bench.window`` host span, else the span of the device events).
+    Device times are exclusive and averaged over the devices seen.
     Seconds throughout."""
     if window is None:
         spans = [h for h in host if h["name"] == "bench.window"]
@@ -182,9 +194,11 @@ def summarize(events, host, window=None, top=10):
         edges = [w0] + [x for s, e in iv for x in (s, e)] + [w1]
         gaps += [(b - a, a, b) for a, b in zip(edges[::2], edges[1::2])
                  if b > a]
-    layer, ops = {}, {}
+    layer, scopes, ops = {}, {}, {}
     for e, d in zip(inside, exclusive_times(inside, w0, w1)):
         layer[e["layer"]] = layer.get(e["layer"], 0.0) + d
+        sc = scope(e["op_name"]) or "untagged"
+        scopes[sc] = scopes.get(sc, 0.0) + d
         key = f"{e['layer']}:{e['name']}"
         ops[key] = ops.get(key, 0.0) + d
     chunks = [(h["start"], h["start"] + h["dur"]) for h in host
@@ -200,6 +214,7 @@ def summarize(events, host, window=None, top=10):
     return dict(
         window_s=(w1 - w0) * ns, busy_s=busy / nd * ns, devices=nd,
         layer_s={k: v / nd * ns for k, v in layer.items()},
+        scope_s={k: v / nd * ns for k, v in scopes.items()},
         device_ops=[[k, v / nd * ns] for k, v in
                     sorted(ops.items(), key=lambda kv: -kv[1])[:top]],
         idle_gaps=[[gap_name(a, b), g * ns] for g, a, b in
