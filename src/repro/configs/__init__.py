@@ -16,6 +16,7 @@ ARCH_IDS = [
     "whisper-tiny",
     "rwkv6-1.6b",
     "zamba2-2.7b",
+    "zamba2-7b",
     "mixtral-8x22b",
     "qwen2.5-32b",
     "phi-3-vision-4.2b",

@@ -42,12 +42,21 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 128
+    ssm_groups: int = 1         # B/C groups (Mamba2 ngroups)
+    ssm_dt_min: float = 0.0     # floor of the step after the softplus (0 => none)
 
     # --- RWKV6 ---
     rwkv_head_size: int = 64
 
     # --- hybrid (zamba2-style): one shared attention block every k layers ---
     attn_every: int = 0
+    # --- hybrid, Zamba2's own pattern (``transformer.zamba2_forward``):
+    # "mamba" | "hybrid" per layer; a hybrid layer runs shared block
+    # ``j % num_mem_blocks`` (j its index among the hybrids) before its
+    # Mamba mixer. Empty => the ``attn_every`` sketch above.
+    layers_block_type: Tuple[str, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0       # the shared MLP's per-invocation adapter
 
     # --- attention window (None => full causal) ---
     sliding_window: Optional[int] = None

@@ -7,7 +7,7 @@ of its local loss"); larger values give standard FedAvg deltas.
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +50,17 @@ def local_update(params, dataset, local_step, n_steps: int):
     return delta, metrics
 
 
+class WithFrozen(NamedTuple):
+    """The one params argument a model's loss and eval are handed when
+    the trainer holds a frozen base: the trained tree (what is
+    differentiated, flattened, sparsified and aggregated) and the base,
+    which only the model reads."""
+    trained: Any
+    frozen: Any
+
+
 def make_batched_client_step(loss_fn: Callable, lr: float, opt_name: str = "sgd",
-                             jit: bool = True, **opt_kw):
+                             jit: bool = True, frozen: bool = False, **opt_kw):
     """Vectorized replacement for the per-client Python loop.
 
     Returns a jitted ``fn(params, batches) -> (updates [N,D], u_norms [N],
@@ -67,12 +76,19 @@ def make_batched_client_step(loss_fn: Callable, lr: float, opt_name: str = "sgd"
 
     ``jit=False`` returns the bare vmapped function for composition into a
     larger traced program (e.g. the multi-round ``lax.scan`` engine).
+
+    ``frozen=True``: the function takes a third argument, a frozen base
+    shared by every client (``vmap`` broadcasts it; no client gets a
+    copy), and ``loss_fn`` is handed ``WithFrozen(params, base)``; only
+    ``params`` is differentiated.
     """
     from repro.fl.updates import flatten_update
 
     opt_init, opt_update = make_optimizer(opt_name, **opt_kw)
 
-    def one_client(params, client_batches):
+    def one_client(params, client_batches, base=None):
+        objective = (loss_fn if not frozen else
+                     lambda p, b: loss_fn(WithFrozen(p, base), b))
         n_steps = jax.tree_util.tree_leaves(client_batches)[0].shape[0]
         # optimizer state initialized once and threaded through the local
         # steps — momentum/Adam moments accumulate across the whole local
@@ -80,11 +96,12 @@ def make_batched_client_step(loss_fn: Callable, lr: float, opt_name: str = "sgd"
         p, state, loss = params, opt_init(params), jnp.float32(0)
         for s in range(n_steps):
             batch = jax.tree_util.tree_map(lambda v: v[s], client_batches)
-            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
+            (loss, _), grads = jax.value_and_grad(objective, has_aux=True)(p, batch)
             p, state = opt_update(grads, state, p, lr)
         delta = jax.tree_util.tree_map(lambda a, b: a - b, p, params)
         vec = flatten_update(delta)
         return vec, jnp.sqrt(jnp.sum(vec * vec)), loss
 
-    batched = jax.vmap(one_client, in_axes=(None, 0))
+    batched = jax.vmap(one_client,
+                       in_axes=(None, 0, None) if frozen else (None, 0))
     return jax.jit(batched) if jit else batched

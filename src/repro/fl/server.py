@@ -83,7 +83,7 @@ from repro.core.rounds import (AsyncConfig, AsyncState, apply_harvest,
 from repro.data.pipeline import (client_sample_keys, sample_client_batches,
                                  sample_round_batches, stack_client_datasets)
 from repro.fl import compression
-from repro.fl.client import make_batched_client_step
+from repro.fl.client import WithFrozen, make_batched_client_step
 from repro.fl.updates import tree_spec, unflatten_update
 from repro.core.hierarchy import HierarchyConfig, wrap_controller
 from repro.sharding.fl import (CLIENTS_AXIS, async_state_specs, axis_names,
@@ -922,7 +922,11 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
     when fault injection or a defended aggregator is active, plus
     ``n_retx``/``n_outage``/``goodput_frac``/``e_retx`` when the link
     subsystem is, plus ``bits``/``e_saved`` when the quantized-payload
-    path is). Wrap in ``jax.jit(..., static_argnames="n_rounds",
+    path is). ``frozen`` (keyword, default None) is a frozen model base
+    that ``client_step`` takes as its third argument and ``eval_fn``
+    reads through ``WithFrozen(params, frozen)``; it is an operand, never
+    part of the carry, and never a constant of the program. Wrap in
+    ``jax.jit(..., static_argnames="n_rounds",
     donate_argnums=(0, 1, 2, 3, 4, 5))`` — or ``vmap`` over ``keys``
     for sweeps.
 
@@ -968,8 +972,15 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
     n_real_keys = n_real if n_real is not None else n_pad_keys
 
     def scan_body(params, ctrl_state, battery, astate, fstate, lstate, data,
-                  keys, start_round, last_round, eval_every, n_rounds: int):
+                  keys, start_round, last_round, eval_every, n_rounds: int,
+                  frozen=None):
         n_local = data.lengths.shape[0]             # per-shard when sharded
+        # the model's view of the params: with a frozen base, the loss and
+        # eval are handed (trained, base); only the trained tree is the
+        # carry that rounds update
+        with_base = ((lambda q: q) if frozen is None
+                     else (lambda q: WithFrozen(q, frozen)))
+        base_arg = () if frozen is None else (frozen,)
         if sharded:
             i0 = jax.lax.axis_index(axes[0])
             for a in axes[1:]:
@@ -995,7 +1006,7 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
                 batches = sample_client_batches(data.arrays, data.lengths,
                                                 ckeys, local_steps, batch)
             with jax.named_scope("fl.client_step"):
-                updates, u_norms, losses = client_step(p, batches)
+                updates, u_norms, losses = client_step(p, batches, *base_arg)
             ckey = jax.random.fold_in(keys["ctrl"], r)
             if linky:
                 p, dec, state, batt, ast, fst, lst, extras = core(
@@ -1022,7 +1033,8 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
             do_eval = ((r % eval_every) == 0) | (r == last_round)
             with jax.named_scope("fl.eval"):
                 acc = jax.lax.cond(do_eval,
-                                   lambda q: eval_fn(q).astype(jnp.float32),
+                                   lambda q: eval_fn(with_base(q)).astype(
+                                       jnp.float32),
                                    lambda q: jnp.float32(jnp.nan), p)
             out = dict(x=dec.x, gamma=dec.gamma, bandwidth=dec.bandwidth,
                        energy=dec.energy, accuracy=acc,
@@ -1058,8 +1070,15 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
     from jax.sharding import PartitionSpec as PS
 
     def scan_fn(params, ctrl_state, battery, astate, fstate, lstate, data,
-                keys, start_round, last_round, eval_every, n_rounds: int):
-        body = functools.partial(scan_body, n_rounds=n_rounds)
+                keys, start_round, last_round, eval_every, n_rounds: int,
+                frozen=None):
+        # a frozen base rides along replicated, after the other operands
+        base_arg = () if frozen is None else (frozen,)
+        base_spec = () if frozen is None else (replicated_specs(frozen),)
+
+        def body(*args):
+            return scan_body(*args[:11], n_rounds=n_rounds,
+                             frozen=args[11] if base_arg else None)
         # only `data` and the stale-update buffer are split (leading
         # client axis); everything else — params, controller state,
         # battery, defense state, link state, keys, round bounds, stacked
@@ -1074,13 +1093,13 @@ def make_scan_engine(*, controller: Controller, spec, weights: jnp.ndarray,
             body, mesh=mesh,
             in_specs=(replicated_specs(params), replicated_specs(ctrl_state),
                       PS(), ast_specs, fst_specs, lst_specs, PS(data_entry),
-                      PS(), PS(), PS(), PS()),
+                      PS(), PS(), PS(), PS()) + base_spec,
             out_specs=(replicated_specs(params), replicated_specs(ctrl_state),
                        PS(), ast_specs, fst_specs, lst_specs, PS()),
             check_vma=False)
         return sharded_fn(params, ctrl_state, battery, astate, fstate,
                           lstate, data, keys, start_round, last_round,
-                          eval_every)
+                          eval_every, *base_arg)
 
     return scan_fn
 
@@ -1169,6 +1188,16 @@ class FederatedTrainer:
     bursty chain — compiles the exact legacy program, same goldens
     contract as ``fault_cfg``.
 
+    ``frozen_params``: a frozen model base (e.g. the base of LoRA
+    fine-tuning; ``model_params`` then holds only the adapters).
+    ``model_loss`` and ``eval_fn`` keep their one params argument and
+    are handed ``repro.fl.client.WithFrozen(params, frozen_params)``;
+    only ``params`` is differentiated, flattened, sparsified,
+    aggregated and checkpointed. The base is an argument of every round
+    program (never donated, never copied per client: the client ``vmap``
+    broadcasts it; replicated on a clients mesh), so it is not a
+    constant of the compiled program. ``run_sweep`` does not take it.
+
     ``use_pallas_compression``: how the top-k runs, as
     ``compression.batch_block_topk``'s ``use_pallas``: ``None`` (default)
     by backend, the Pallas kernel on a TPU and the jnp bisection
@@ -1190,10 +1219,13 @@ class FederatedTrainer:
                  defense: Optional[DefenseConfig] = None,
                  link_cfg: Optional[LinkConfig] = None,
                  hierarchy: Optional[HierarchyConfig] = None,
-                 mobility=None):
+                 mobility=None, frozen_params=None):
         if strategy is not None:
             controller = strategy
         self.loss_fn = model_loss
+        # held by reference: an argument of every engine call, never
+        # donated, copied or baked into a program
+        self.frozen_params = frozen_params
         # private copy: the fused engine donates the params buffer, which
         # must never consume the caller's (possibly shared) arrays
         self.params = jax.tree_util.tree_map(jnp.array, model_params)
@@ -1259,8 +1291,9 @@ class FederatedTrainer:
         self.harvest_key = jax.random.fold_in(base, _HARVEST_STREAM)
         self.fault_key = jax.random.fold_in(base, _FAULT_STREAM)
         self.link_key = jax.random.fold_in(base, _LINK_STREAM)
-        self._client_step_raw = make_batched_client_step(model_loss, fl_cfg.lr,
-                                                         jit=False)
+        self._client_step_raw = make_batched_client_step(
+            model_loss, fl_cfg.lr, jit=False,
+            frozen=frozen_params is not None)
         self._client_step = jax.jit(self._client_step_raw)
         self._scan_engine = None
         self._scan_fn_raw = None
@@ -1643,7 +1676,8 @@ class FederatedTrainer:
             return
         if not getattr(self.controller, "needs_calibration", False):
             return
-        _, u_norms, _ = self._client_step(self.params, self._round_batches(r))
+        _, u_norms, _ = self._client_step(self.params, self._round_batches(r),
+                                          *self._base_arg())
         h = self.network.gains(r)
         # drop ghost-padded rows: calibration medians see only real clients
         self.controller.calibrate(np.asarray(u_norms)[:self.n_clients],
@@ -1670,7 +1704,8 @@ class FederatedTrainer:
              self._fstate, self._lstate, outs) = engine(
                 self.params, self.ctrl_state, self._battery, self._astate,
                 self._fstate, self._lstate, self._data, self._keys(),
-                jnp.int32(r), jnp.int32(r), jnp.int32(1), n_rounds=1)
+                jnp.int32(r), jnp.int32(r), jnp.int32(1), n_rounds=1,
+                frozen=self.frozen_params)
         self._append_chunk_logs(r, outs)
         return self.history[-1]
 
@@ -1686,6 +1721,9 @@ class FederatedTrainer:
         return self.history
 
     # ------------------------------------------------------- fused engine ----
+    def _base_arg(self) -> tuple:
+        return () if self.frozen_params is None else (self.frozen_params,)
+
     def _keys(self):
         return {"fade": self.network.fade_key, "sample": self.sample_key,
                 "ctrl": self.key, "harvest": self.harvest_key,
@@ -1773,7 +1811,8 @@ class FederatedTrainer:
                     self.params, self.ctrl_state, self._battery,
                     self._astate, self._fstate, self._lstate, self._data,
                     keys, jnp.int32(s), jnp.int32(rounds - 1),
-                    jnp.int32(eval_every), n_rounds=n)
+                    jnp.int32(eval_every), n_rounds=n,
+                    frozen=self.frozen_params)
             self._append_chunk_logs(s, outs)
             if ckpt_dir is not None and ((ci + 1) % ckpt_every == 0
                                          or s + n >= rounds):
@@ -1798,7 +1837,7 @@ class FederatedTrainer:
             self.params, self.ctrl_state, self._battery, self._astate,
             self._fstate, self._lstate, self._data, self._keys(),
             jnp.int32(0), jnp.int32(rounds - 1), jnp.int32(eval_every),
-            n_rounds=rounds)
+            n_rounds=rounds, frozen=self.frozen_params)
 
     # ------------------------------------------------------- checkpointing ----
     def _carry_tree(self) -> dict:
@@ -1888,6 +1927,10 @@ class FederatedTrainer:
         rounds = rounds or self.fl_cfg.rounds
         if eval_every < 1:
             raise ValueError(f"eval_every must be >= 1, got {eval_every}")
+        if self.frozen_params is not None:
+            raise NotImplementedError(
+                "run_sweep with frozen_params: the sweep engines do not "
+                "take a frozen base; run one trainer per seed")
         self._maybe_calibrate(0)
         bases = [jax.random.PRNGKey(int(s)) for s in seeds]
         if configs is not None:

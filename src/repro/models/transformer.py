@@ -6,7 +6,14 @@ Families:
   moe     — GQA attention + MoE FFN          (qwen2-moe, mixtral-8x22b)
   ssm     — RWKV6 time-mix + channel-mix     (rwkv6-1.6b)
   hybrid  — Mamba2 backbone + ONE shared attention block applied every
-            ``attn_every`` layers (parameters shared — zamba2-style)
+            ``attn_every`` layers (parameters shared — zamba2-style), or,
+            with ``cfg.layers_block_type``, Zamba2 itself
+            (``zamba2_forward``, training forward only). Its departures
+            from ``transformers``' ``modeling_zamba2``: the SSD runs in
+            chunks of ``ssm_chunk`` (the same sums in another order; the
+            public multi-chunk path itself departs from its one-chunk
+            result by about 1e-5), and there is no attention mask, cache
+            or shared attention adapter (Zamba2-7B uses none)
 
 Layers are stacked and consumed by ``lax.scan`` (with ``jax.checkpoint``
 when cfg.remat) so the HLO stays small and the remat policy is explicit.
@@ -20,11 +27,12 @@ import jax
 import jax.numpy as jnp
 
 from . import attention as attn
+from . import lora
 from . import moe as moe_mod
 from . import rwkv as rwkv_mod
 from . import ssm as ssm_mod
-from .layers import (layernorm, layernorm_init, rmsnorm, rmsnorm_init,
-                     swiglu, swiglu_init)
+from .layers import (apply_rope, layernorm, layernorm_init, rmsnorm,
+                     rmsnorm_init, swiglu, swiglu_init)
 from .module import (Params, dtype_of, embed, embed_init, stack_init, unembed,
                      dense_init, dense, scan_layers)
 from repro.sharding.act import constrain
@@ -63,6 +71,8 @@ def _shared_attn_block_init(key, cfg) -> Params:
 
 # ------------------------------------------------------------------ init ----
 def init_lm(key, cfg) -> Params:
+    if cfg.layers_block_type:
+        return zamba2_init(key, cfg)
     ke, kl, kh, ks = jax.random.split(key, 4)
     p: Params = {"embed": embed_init(ke, cfg.vocab_size, cfg.d_model),
                  "ln_f": (layernorm_init if cfg.family == "ssm" else rmsnorm_init)(cfg.d_model)}
@@ -87,8 +97,8 @@ def init_lm(key, cfg) -> Params:
 
 
 # --------------------------------------------------------------- forward ----
-def _maybe_ckpt(fn, cfg):
-    return jax.checkpoint(fn) if cfg.remat else fn
+def _maybe_ckpt(fn, cfg, **kw):
+    return jax.checkpoint(fn, **kw) if cfg.remat else fn
 
 
 def _window_for(cfg, seq_len: int) -> Optional[int]:
@@ -109,6 +119,8 @@ def lm_forward(params: Params, tokens: Array, cfg, *,
                window: Optional[int] = None) -> tuple[Array, Array]:
     """tokens: [B, S_text] int32. extra_embeds (vlm/audio): [B, S_vis, d]
     prepended to the token embeddings. Returns (logits [B,S,V], aux_loss)."""
+    if cfg.layers_block_type:
+        return zamba2_forward(params, tokens, cfg), jnp.float32(0)
     dt = dtype_of(cfg)
     x = embed(params["embed"], tokens, dt)
     if extra_embeds is not None:
@@ -185,12 +197,168 @@ def lm_loss(params: Params, batch: dict, cfg) -> tuple[Array, dict]:
     return loss + aux, {"xent": loss, "aux": aux}
 
 
+# ---------------------------------------------------------------- zamba2 ----
+# Zamba2 (arXiv:2411.15242) as ``transformers``' ``modeling_zamba2`` computes
+# it: every layer has a Mamba2 mixer (pre-RMSNorm, residual); a "hybrid"
+# layer first runs shared transformer block ``j % num_mem_blocks`` (j its
+# index among the hybrids) on ``[h, embedding]`` (width 2d): RMSNorm,
+# attention of ``n_heads`` heads of ``head_dim`` with RoPE and scale
+# ``(head_dim / 2) ** -0.5``, no residual, RMSNorm, a gated-GELU MLP whose
+# ``gate_up`` adds the invocation's own rank-``adapter_rank`` adapter, no
+# residual; then the invocation's own ``linear`` (d -> d), whose output
+# is added to the Mamba layer's input (not to its residual). Final
+# RMSNorm and an embedding-tied head. Departures: module docstring.
+ZAMBA2_LORA_TARGETS = ("in_proj", "out_proj", "q", "k", "v", "o", "gate_up",
+                       "down", "linear")
+
+
+def _zamba2_block_init(key, cfg) -> Params:
+    d, d2, hd = cfg.d_model, 2 * cfg.d_model, cfg.resolved_head_dim
+    kq, kk, kv, ko, kg, kd = jax.random.split(key, 6)
+    return {"attn_norm": rmsnorm_init(d2),
+            "q": dense_init(kq, d2, cfg.n_heads * hd),
+            "k": dense_init(kk, d2, cfg.n_kv_heads * hd),
+            "v": dense_init(kv, d2, cfg.n_kv_heads * hd),
+            "o": dense_init(ko, cfg.n_heads * hd, d),
+            "mlp_norm": rmsnorm_init(d),
+            "gate_up": dense_init(kg, d, 2 * cfg.d_ff),
+            "down": dense_init(kd, cfg.d_ff, d)}
+
+
+def _zamba2_hybrid_init(key, cfg) -> Params:
+    d, r = cfg.d_model, cfg.adapter_rank
+    ka, kb, kl = jax.random.split(key, 3)
+    return {"adapter_a": dense_init(ka, d, r),
+            "adapter_b": dense_init(kb, r, 2 * cfg.d_ff),
+            "linear": dense_init(kl, d, d)}
+
+
+def zamba2_init(key, cfg) -> Params:
+    """``embed`` (tied head), ``final_norm``, ``layers`` (the Mamba layers,
+    stacked), ``blocks`` (the shared blocks) and ``hybrid`` (each
+    invocation's MLP adapter and ``linear``)."""
+    n_hybrid = cfg.layers_block_type.count("hybrid")
+    ke, kl, kb, kh = jax.random.split(key, 4)
+    return {"embed": embed_init(ke, cfg.vocab_size, cfg.d_model),
+            "final_norm": rmsnorm_init(cfg.d_model),
+            "layers": stack_init(_mamba_layer_init, kl, cfg.n_layers, cfg),
+            "blocks": [_zamba2_block_init(k, cfg)
+                       for k in jax.random.split(kb, cfg.num_mem_blocks)],
+            "hybrid": [_zamba2_hybrid_init(k, cfg)
+                       for k in jax.random.split(kh, n_hybrid)]}
+
+
+def _zamba2_mamba(layer, adapters, h, t, cfg, scale):
+    """One Mamba layer: ``h + mamba(norm(h + t))``, t the shared block's
+    contribution on a hybrid layer."""
+    with jax.named_scope("fl.mamba"):
+        x = rmsnorm(layer["ln"], h if t is None else h + t, cfg.norm_eps)
+        return h + ssm_mod.mamba2_forward(
+            layer["mamba"], x, cfg, adapters=adapters and adapters["mamba"],
+            lora_scale=scale)
+
+
+def _zamba2_shared(block, hybrid, ad_block, ad_hybrid, h, emb, cfg, scale):
+    """A shared block's invocation and its ``linear``: [B,S,d] -> [B,S,d]."""
+    def lin(params, adapters, name, x):
+        return lora.linear(params[name], x, (adapters or {}).get(name), scale)
+
+    with jax.named_scope("fl.shared_block"):
+        B, S, d = h.shape
+        H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+        x = rmsnorm(block["attn_norm"], jnp.concatenate([h, emb], -1),
+                    cfg.norm_eps)
+        pos = jnp.arange(S)
+        q = apply_rope(lin(block, ad_block, "q", x).reshape(B, S, H, hd), pos,
+                       cfg.rope_theta)
+        k = apply_rope(lin(block, ad_block, "k", x).reshape(B, S, KV, hd), pos,
+                       cfg.rope_theta)
+        v = lin(block, ad_block, "v", x).reshape(B, S, KV, hd)
+        k, v = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (hd / 2) ** -0.5
+        causal = jnp.tril(jnp.ones((S, S), bool))
+        probs = jax.nn.softmax(jnp.where(causal, scores.astype(jnp.float32),
+                                         attn.NEG_INF), axis=-1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+        o = lin(block, ad_block, "o", o.reshape(B, S, H * hd))
+        x = rmsnorm(block["mlp_norm"], o, cfg.norm_eps)
+        gate_up = lin(block, ad_block, "gate_up", x) + dense(
+            hybrid["adapter_b"], dense(hybrid["adapter_a"], x))
+        gate, up = jnp.split(gate_up, 2, axis=-1)
+        y = lin(block, ad_block, "down",
+                jax.nn.gelu(gate, approximate=False) * up)
+        return lin(hybrid, ad_hybrid, "linear", y)
+
+
+def zamba2_forward(params: Params, tokens: Array, cfg,
+                   adapters: Optional[Params] = None,
+                   lora_scale: float = 1.0) -> Array:
+    """tokens [B, S] -> logits [B, S, V] (float32). ``adapters``: LoRA
+    pairs over ``ZAMBA2_LORA_TARGETS`` (``lora.init``), applied unmerged
+    with scale ``lora_scale``. Runs of Mamba layers go through one
+    ``lax.scan`` each (the layer indexed out of the stack inside the
+    body); with ``cfg.remat`` every layer is rematerialised."""
+    ad = adapters or {}
+    ckpt = partial(_maybe_ckpt, cfg=cfg)
+    emb = embed(params["embed"], tokens, dtype_of(cfg))
+    stack, ad_stack = params["layers"], ad.get("layers")
+    ad_blocks = ad.get("blocks") or [None] * len(params["blocks"])
+    ad_hybrid = ad.get("hybrid") or [None] * len(params["hybrid"])
+
+    def layer_at(i):
+        take = lambda t: jax.lax.dynamic_index_in_dim(t, i, keepdims=False)
+        return (jax.tree_util.tree_map(take, stack),
+                jax.tree_util.tree_map(take, ad_stack))
+
+    def mamba_body(h, i):
+        layer, a = layer_at(i)
+        return _zamba2_mamba(layer, a, h, None, cfg, lora_scale), None
+
+    def hybrid_layer(h, i, j):
+        b = j % cfg.num_mem_blocks
+        t = _zamba2_shared(params["blocks"][b], params["hybrid"][j],
+                           ad_blocks[b], ad_hybrid[j], h, emb, cfg,
+                           lora_scale)
+        layer, a = layer_at(i)
+        return _zamba2_mamba(layer, a, h, t, cfg, lora_scale)
+
+    h, run, j = emb, [], 0
+    for i, kind in enumerate(tuple(cfg.layers_block_type) + ("end",)):
+        if kind == "mamba":
+            run.append(i)
+            continue
+        if run:
+            h, _ = jax.lax.scan(ckpt(mamba_body), h,
+                                jnp.arange(run[0], run[-1] + 1))
+            run = []
+        if kind == "hybrid":
+            h = ckpt(hybrid_layer, static_argnums=(1, 2))(h, i, j)
+            j += 1
+    x = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return unembed(params["embed"], x)
+
+
+def zamba2_loss(params: Params, batch: dict, cfg,
+                adapters: Optional[Params] = None,
+                lora_scale: float = 1.0) -> tuple[Array, dict]:
+    """Mean next-token cross-entropy of ``batch["tokens"]`` [B, S + 1]
+    over its S targets."""
+    tokens = batch["tokens"]
+    logits = zamba2_forward(params, tokens[:, :-1], cfg, adapters, lora_scale)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
+    loss = jnp.mean(nll)
+    return loss, {"xent": loss}
+
+
 # --------------------------------------------------------------- prefill ----
 def lm_prefill(params: Params, tokens: Array, cfg, *, cache_len: int,
                extra_embeds: Optional[Array] = None,
                window: Optional[int] = None) -> tuple[Array, Params]:
     """Serving prefill: forward pass that also materializes a decode-ready
     cache (ring KV / SSM state). Returns (last-token logits [B,1,V], cache)."""
+    if cfg.layers_block_type:
+        raise NotImplementedError("zamba2_forward has no serving cache")
     dt = dtype_of(cfg)
     x = embed(params["embed"], tokens, dt)
     if extra_embeds is not None:

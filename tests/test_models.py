@@ -82,6 +82,7 @@ def test_full_config_shapes_match_assignment():
         "whisper-tiny": (4, 384, 6, 6, 51865),
         "rwkv6-1.6b": (24, 2048, 0, 0, 65536),
         "zamba2-2.7b": (54, 2560, 32, 32, 32000),
+        "zamba2-7b": (81, 3584, 32, 32, 32000),
         "mixtral-8x22b": (56, 6144, 48, 8, 32768),
         "qwen2.5-32b": (64, 5120, 40, 8, 152064),
         "phi-3-vision-4.2b": (32, 3072, 32, 32, 32064),
