@@ -4,6 +4,7 @@ dual_solve      — fused bandwidth best-response + gamma selection (solver)
 topk_sparsify   — block-local magnitude top-k (the paper's compression)
 score_norm      — fused sum-of-squares reduction (contribution score)
 flash_attention — block-tiled causal/SWA GQA attention
+ssd             — Mamba2's chunked SSD scan, forward and backward
 
 Every ``ops.py`` wrapper asks ``interpret_mode()`` at call time, so the
 same code runs the kernels compiled on a TPU and in the Pallas

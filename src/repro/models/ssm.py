@@ -1,10 +1,12 @@
-"""Mamba2 (SSD) block — TPU-native chunked-scan implementation.
+"""Mamba2 (SSD) block.
 
-GPU Mamba uses a fused selective-scan CUDA kernel; the TPU adaptation
-(DESIGN.md §4.5) uses the SSD chunkwise form: the sequence is split into
-chunks of ``cfg.ssm_chunk``; within a chunk the recurrence is evaluated as
-dense (MXU-friendly) matmuls against a decay-masked [Q,Q] matrix, and state
-is propagated across chunks with a single ``lax.scan``.
+GPU Mamba uses a fused selective-scan CUDA kernel; this one uses the SSD
+chunkwise form (``repro.kernels.ssd``): the sequence is split into chunks
+of ``cfg.ssm_chunk``; within a chunk the recurrence is evaluated against a
+decay-masked [Q,Q] matrix per head, and the state is carried from chunk
+to chunk. On a TPU, where the chunks tile, a Pallas kernel pair keeps each
+chunk's [Q,Q] arrays in VMEM (the jnp form writes them, and their
+cotangents, to HBM); elsewhere the jnp scan runs.
 
 Recurrence (per head h, state size N, head dim P):
     a_t = exp(dt_t * A_h)                       (scalar decay per step)
@@ -24,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from . import lora
+from ..kernels.ssd import ops as ssd_ops
 from .layers import rmsnorm_init
 from .module import Params, dense_init
 
@@ -99,67 +102,24 @@ def mamba2_forward(params: Params, x: Array, cfg, *, return_state: bool = False,
     P = cfg.ssm_head_dim
     G = cfg.ssm_groups
     z, xBC, dt, d_inner, N, H = _split_proj(params, x, cfg, adapters, lora_scale)
-    Hg = H // G
     xBC_raw = xBC
     xBC = _causal_conv(xBC, params["conv_w"], params["conv_b"])
     xs, Bmat, Cmat = jnp.split(xBC, [d_inner, d_inner + G * N], axis=-1)
-    xh = xs.reshape(B, S, H, P)
-
     dt = _step_size(dt, params, cfg)                                      # [B,S,H]
     A = -jnp.exp(params["A_log"])                                          # [H]
     log_a = dt * A[None, None, :]                                          # [B,S,H] (<0)
 
-    Q = min(cfg.ssm_chunk, S)
-    assert S % Q == 0, (S, Q)
-    nc = S // Q
-
-    def to_chunks(t, trailing):
-        return t.reshape((B, nc, Q) + trailing).swapaxes(0, 1)
-
-    # heads as [G, Hg]: head h reads group h // Hg's B and C
-    xc = to_chunks(xh.astype(jnp.float32), (G, Hg, P))       # [nc,B,Q,G,Hg,P]
-    Bc = to_chunks(Bmat.astype(jnp.float32), (G, N))         # [nc,B,Q,G,N]
-    Cc = to_chunks(Cmat.astype(jnp.float32), (G, N))
-    dtc = to_chunks(dt, (G, Hg))                              # [nc,B,Q,G,Hg]
-    lac = to_chunks(log_a, (G, Hg))
-
-    def chunk_step(S_prev, inputs):
-        xq, Bq, Cq, dtq, laq = inputs
-        # per head, time last: [B,G,Hg,Q] cumulative log decay
-        L = jnp.moveaxis(jnp.cumsum(laq, axis=1), 1, -1)
-        # intra-chunk: M[t,s] = (C_t.B_s) exp(L_t - L_s) dt_s, s<=t, laid
-        # out [B,G,Hg,t,s] so the lane axis is the chunk, not the heads
-        CB = jnp.einsum("bqgn,bsgn->bgqs", Cq, Bq)           # [B,G,Q,Q]
-        diff = L[..., :, None] - L[..., None, :]             # [B,G,Hg,t,s]
-        mask = jnp.tril(jnp.ones((Q, Q), bool))
-        # mask INSIDE the exp: where(mask, exp(diff), 0) has a 0*inf = NaN
-        # cotangent for the masked (diff>0) entries
-        decay = jnp.exp(jnp.where(mask, diff, -1e9))
-        M = CB[:, :, None] * decay * jnp.moveaxis(dtq, 1, -1)[..., None, :]
-        y_intra = jnp.einsum("bghts,bsghp->btghp", M, xq)
-        # inter-chunk: y_inter[t] = exp(L_t) * C_t^T S_prev
-        y_inter = jnp.einsum("bqgn,bghnp->bqghp", Cq, S_prev) * \
-            jnp.moveaxis(jnp.exp(L), -1, 1)[..., None]
-        # state update
-        rem = jnp.moveaxis(jnp.exp(L[..., -1:] - L), -1, 1)  # exp(L_Q - L_s)
-        Sc = jnp.einsum("bsgn,bsghp->bghnp", Bq, xq * (rem * dtq)[..., None])
-        S_new = jnp.exp(L[..., -1])[..., None, None] * S_prev + Sc
-        return S_new, y_intra + y_inter
-
-    if cfg.remat:   # the backward keeps one chunk's [Q, Q] decays at a time
-        chunk_step = jax.checkpoint(chunk_step)
-
-    S0 = jnp.zeros((B, G, Hg, N, P), jnp.float32)
-    S_final, ys = jax.lax.scan(chunk_step, S0, (xc, Bc, Cc, dtc, lac))
-    y = ys.swapaxes(0, 1).reshape(B, S, H, P)
-    y = y + params["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(B, S, d_inner).astype(x.dtype)
+    y, S_final = ssd_ops.ssd_scan(xs, Bmat, Cmat, dt, log_a,
+                                  chunk=min(cfg.ssm_chunk, S), groups=G,
+                                  head_dim=P, remat=cfg.remat)
+    y = y + jnp.repeat(params["D"], P)[None, None, :] * xs.astype(jnp.float32)
+    y = y.astype(x.dtype)
     out = _out_proj(params, _gated_norm(params, y, z, cfg), adapters, lora_scale)
     if return_state:
         K = cfg.ssm_conv
         conv_tail = xBC_raw[:, S - (K - 1):, :] if S >= K - 1 else jnp.pad(
             xBC_raw, ((0, 0), (K - 1 - S, 0), (0, 0)))
-        return out, {"conv": conv_tail, "state": S_final.reshape(B, H, N, P)}
+        return out, {"conv": conv_tail, "state": S_final}
     return out
 
 

@@ -8,10 +8,14 @@ that stops lowering for the chip fails here and not on the chip.
 
 Widths are the paper's: N = 1024 clients for the solver kernels, the
 1.6M-parameter CNN (``configs.fmnist_cnn``) at N = 50 clients for top-k
-and the score norm. The topology is described inside module fixtures —
-never at import — and each test skips only when it cannot be described.
+and the score norm; Zamba2-7B's mixer at its published widths, as the
+``zamba2-lora-n20.fairenergy`` cell trains it, for the SSD kernels. The
+topology is described inside module fixtures — never at import — and
+each test skips only when it cannot be described.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +29,9 @@ from repro.kernels.dual_solve.kernel import (N_SCALARS, dual_solve_pallas,
 from repro.kernels.dual_solve.ref import joint_levels
 from repro.kernels.score_norm.kernel import sq_sum_partials
 from repro.kernels.topk_sparsify.kernel import topk_sparsify_matrix_pallas
-from repro.models import cnn
+from repro.configs.zamba2_7b import CONFIG as ZAMBA2
+from repro.kernels.ssd import ops as ssd_ops
+from repro.models import cnn, lora, transformer as tfm
 
 N_SOLVER = 1024
 N_CLIENTS = 50
@@ -129,3 +135,55 @@ def test_sq_sum_partials_compiles_for_v5e(one_chip, cnn_width):
     n = -(-cnn_width // block) * block
     vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
     _compile(lambda v: sq_sum_partials(v, block=block, interpret=False), vec)
+
+
+def _mixer_step(one_chip, monkeypatch, use_pallas):
+    """One Zamba2-7B mixer's training step as the Zamba2 cell runs it,
+    compiled: 20 clients under ``vmap``, one sequence of 512 tokens each,
+    the bfloat16 base, LoRA rank 32 on ``in_proj`` and ``out_proj``, the
+    layer under ``jax.checkpoint``; the cotangents of its input and
+    adapters from one of its output."""
+    monkeypatch.setattr(ssd_ops, "interpret_mode", lambda: False)
+    monkeypatch.setattr(ssd_ops, "ssd_scan", functools.partial(
+        ssd_ops.ssd_scan, use_pallas=use_pallas))
+    cfg = ZAMBA2.replace(dtype="float32")
+    clients, seq = 20, 512
+    layer = jax.eval_shape(
+        lambda: tfm._mamba_layer_init(jax.random.PRNGKey(0), cfg))
+    adapters = jax.eval_shape(lambda: lora.init(
+        jax.random.PRNGKey(1), layer["mamba"], {"in_proj", "out_proj"}, 32))
+    base = jax.tree_util.tree_map(lambda t: jax.ShapeDtypeStruct(
+        t.shape, jnp.bfloat16, sharding=one_chip), layer)
+    adapters = jax.tree_util.tree_map(lambda t: jax.ShapeDtypeStruct(
+        (clients,) + t.shape, t.dtype, sharding=one_chip), adapters)
+    h = jax.ShapeDtypeStruct((clients, 1, seq, cfg.d_model), jnp.float32,
+                             sharding=one_chip)
+
+    def step(layer, adapters, h, dout):
+        def one(a, h, dout):
+            f = jax.checkpoint(lambda h, a: tfm._zamba2_mamba(
+                layer, {"mamba": a}, h, None, cfg, 2.0))
+            _, vjp = jax.vjp(f, h, a)
+            return vjp(dout)
+        return jax.vmap(one)(adapters, h, dout)
+
+    return jax.jit(step).lower(base, adapters, h, h).compile()
+
+
+def test_ssd_kernels_compile_for_the_zamba2_mixer(one_chip, monkeypatch):
+    """The mixer's step runs the SSD kernel pair (``tpu_custom_call``s
+    named ``ssd_chunk_fwd_pallas`` and ``ssd_chunk_bwd_pallas``), holds
+    no float32 buffer of [256, 256] (the jnp scan's per-chunk decays and
+    weights, [20, 2, 56, 256, 256] per head), and needs fewer temporaries
+    than the jnp scan does there."""
+    kernels = _mixer_step(one_chip, monkeypatch, True)
+    text = kernels.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"ssd_chunk_fwd_pallas[.\d]* = ", text)
+    assert re.search(r"ssd_chunk_bwd_pallas[.\d]* = ", text)
+    chunk_square = r"f32\[[\d,]*256,256\]"
+    assert not re.search(chunk_square, text)
+    jnp_scan = _mixer_step(one_chip, monkeypatch, False)
+    assert re.search(chunk_square, jnp_scan.as_text())
+    assert (kernels.memory_analysis().temp_size_in_bytes
+            < jnp_scan.memory_analysis().temp_size_in_bytes)
